@@ -19,7 +19,6 @@ this against the uninstrumented study.
 
 STUDY_SNIPPET = r'''
 from jax.sharding import PartitionSpec as _StudyP
-from jax.experimental.shard_map import shard_map as _study_shard_map
 from repro import transport as _study_tp
 from repro import wire as _study_wire
 from repro.core.exchange import exchange_window as _study_xw
@@ -67,9 +66,9 @@ def make_study(backend, opts, recorder_depth=None):
         return lift(stats) + (lift(ring),)
     spec = _StudyP("wafer")
     n_out = 2 if recorder_depth is None else 3
-    fn = _study_shard_map(body, mesh=mesh, in_specs=(spec,) * 4,
-                          out_specs=(spec,) * n_out if n_out == 3 else spec,
-                          check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 4,
+                       out_specs=(spec,) * n_out if n_out == 3 else spec,
+                       check_vma=False)
     return jax.jit(lambda: fn(words, stacked.dest_of_addr,
                               stacked.guid_of_addr, stacked.mcast_of_guid))
 '''

@@ -26,7 +26,8 @@ def exchange_walltime(report, n_events: int = 4096, capacity: int = 256):
 
     n_shards = 1                           # in-process mesh: 1 host device
     n_addr = 1 << 12
-    mesh = jax.make_mesh((n_shards,), ("wafer",))
+    from repro.launch.mesh import make_wafer_mesh
+    mesh = make_wafer_mesh(n_shards)
     projs = [rt.Projection(a, a + 1, dest_node=a % n_shards,
                            dest_links=[a % 8]) for a in range(n_addr)]
     t = rt.build_tables(n_addr, projs)

@@ -10,18 +10,12 @@ biological-real-time slowdown, the delivery ratio, detour (reroute)
 counts and the p99 latency degradation against the no-fault baseline —
 the chaos-engineering counterpart of the paper's commissioning runs.
 
-Needs 8 devices, so the timed work runs in a subprocess with
-``xla_force_host_platform_device_count=8`` (the harness process has
-already initialized single-device jax), like ``bench_transport``.
+Needs 8 devices, so the timed work runs in a process of its own with
+``xla_force_host_platform_device_count=8``, like ``bench_transport``.
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 SCRIPT = r'''
 import os
@@ -38,7 +32,8 @@ cap, cred = params["capacity"], params["credits"]
 spec = mc.MicrocircuitSpec(scale=scale)
 w, is_inh = spec.weight_matrix()
 part = network.build_partition(w, is_inh, n_shards=8)
-mesh = jax.make_mesh((8,), ("wafer",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(8)
 dims = (2, 2, 2)
 cfg = sim.SimConfig(n_shards=8, per_shard=part.per_shard,
                     max_fan=part.fanout.shape[1], window=8, ring_len=32,
@@ -139,18 +134,7 @@ def main(report) -> None:
         params["trace_dir"] = os.path.abspath(report.trace_dir)
     spec = mc.MicrocircuitSpec(scale=params["scale"])
     report("microcircuit/neurons", spec.n_neurons, f"scale={spec.scale}")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-c", SCRIPT, json.dumps(params)],
-        capture_output=True, text=True, timeout=2400, env=env)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"bench_microcircuit subprocess failed:\n"
-            f"{out.stdout}\n{out.stderr}")
-    line = [l for l in out.stdout.splitlines()
-            if l.startswith("BENCH_JSON ")][0]
-    for row in json.loads(line[len("BENCH_JSON "):]):
+    for row in report.run_script(SCRIPT, params, timeout=2400):
         extra = {k: row[k] for k in (
             "fault", "mesh", "bio_slowdown", "spikes", "delivery_ratio",
             "delivery_vs_healthy", "rerouted", "parked", "deferred",

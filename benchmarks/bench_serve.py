@@ -23,12 +23,7 @@ Rows in ``BENCH_serve.json``:
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 # quiet-tenant p99 under a saturating co-tenant may not exceed its solo
 # p99 by more than this factor (2 log-2 histogram bins: the bounded
@@ -42,7 +37,6 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
 import json, sys
 import numpy as np
 import jax
-from jax.sharding import Mesh
 
 from repro.serve.loadgen import PoissonLoadGen, TenantProfile
 from repro.serve.spike_engine import EngineConfig, SpikeEngine
@@ -52,7 +46,8 @@ params = json.loads(sys.argv[1])
 C = params["capacity"]
 segments = params["segments"]
 n = 8
-mesh = Mesh(np.array(jax.devices()[:n]), ("w",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(n, "w")
 cfg = EngineConfig(capacity=C, link_credits=params["link_credits"],
                    notify_latency=2, window_us=100.0,
                    seg_windows=params["seg_windows"], nx=2, ny=2, nz=2)
@@ -158,17 +153,7 @@ def main(report) -> None:
     }
     if report.trace_dir:
         params["trace_dir"] = os.path.abspath(report.trace_dir)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-c", SCRIPT, json.dumps(params)],
-        capture_output=True, text=True, timeout=1800, env=env)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"bench_serve subprocess failed:\n{out.stdout}\n{out.stderr}")
-    line = [l for l in out.stdout.splitlines()
-            if l.startswith("BENCH_JSON ")][0]
-    for row in json.loads(line[len("BENCH_JSON "):]):
+    for row in report.run_script(SCRIPT, params, timeout=1800):
         extra = {k: row[k] for k in row
                  if k not in ("op", "median_ms", "events_per_s", "shape")}
         notes = ""

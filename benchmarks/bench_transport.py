@@ -9,22 +9,14 @@ across a scan of sustained windows) so the in-fabric transit buffers
 show rows parking mid-route AND resuming: the study row carries
 ``parked`` / ``unparked`` / ``hop0_reentries`` / ``dwell_us`` /
 ``latency_p99_us``.  Needs 8 devices, so the timed work runs in a
-subprocess with ``xla_force_host_platform_device_count=8`` (the harness
-process has already initialized single-device jax); results feed
+process of its own with ``xla_force_host_platform_device_count=8``; results feed
 ``BENCH_transport.json`` with backend, mesh shape, median_ms,
 events_per_s and credit_stalls per row (see docs/benchmarks.md for the
 full schema).
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-
 from benchmarks._fabric_study import STUDY_SNIPPET
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 SCRIPT = r'''
 import os
@@ -160,17 +152,7 @@ def main(report) -> None:
     # throttle to roughly half the typical per-link demand so stalls
     # occur, but never below the bucket capacity (admission invariant)
     params["credits"] = max(params["n"] // 8, params["c"])
-    env = dict(os.environ)
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-c", SCRIPT, json.dumps(params)],
-        capture_output=True, text=True, timeout=1200, env=env)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"bench_transport subprocess failed:\n{out.stdout}\n{out.stderr}")
-    line = [l for l in out.stdout.splitlines()
-            if l.startswith("BENCH_JSON ")][0]
-    for row in json.loads(line[len("BENCH_JSON "):]):
+    for row in report.run_script(SCRIPT, params, timeout=1200):
         extra = {k: row[k] for k in (
             "backend", "mesh", "credit_stalls", "hops", "forwarded_bytes",
             "stalled_by_hop", "parked", "dwell_us", "unparked",

@@ -21,14 +21,7 @@ uncongested p50 is untouched.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-
 from benchmarks._fabric_study import STUDY_SNIPPET
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 SCRIPT = r'''
 import os
@@ -168,17 +161,7 @@ def main(report) -> None:
         "iters": 5 if report.smoke else 15,
         "windows": 4 if report.smoke else 6,
     }
-    env = dict(os.environ)
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-c", SCRIPT, json.dumps(params)],
-        capture_output=True, text=True, timeout=1800, env=env)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"bench_wire subprocess failed:\n{out.stdout}\n{out.stderr}")
-    line = [l for l in out.stdout.splitlines()
-            if l.startswith("BENCH_JSON ")][0]
-    for row in json.loads(line[len("BENCH_JSON "):]):
+    for row in report.run_script(SCRIPT, params, timeout=1800):
         op = f"{row['backend']}/{row['wire_format']}"
         extra = {k: row[k] for k in row
                  if k not in ("median_ms", "events_per_s", "shape")}

@@ -24,6 +24,11 @@ artifacts without one.  ``--trace`` additionally writes observability
 run directories (``repro.obs``: flight-recorder rows, Perfetto trace,
 Prometheus metrics) for the modules that support it.
 
+Each module runs in a spawned process of its own, and this process never
+initialises JAX: a module that needs N host devices starts its measuring
+script through ``Reporter.run_script``, and on a TPU only one process may
+hold the chip.
+
 ``--smoke`` runs a reduced module set with shrunk shapes — fast enough for
 the tier-1 time budget while still producing all the JSON files.  Smoke
 rows are stamped ``"smoke": true`` and must NEVER be committed: the
@@ -61,12 +66,23 @@ from __future__ import annotations
 
 import argparse
 import json
+import multiprocessing
 import os
 import subprocess
 import sys
 import time
+from concurrent.futures import ProcessPoolExecutor
 
 from repro.obs import log as obs_log
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# appended to every measuring script: the process that measured reports
+# what it ran on
+_PROVENANCE_TAIL = """
+from benchmarks.run import provenance as _provenance
+print("BENCH_PROVENANCE " + json.dumps(_provenance()))
+"""
 
 MODULES = [
     "bench_aggregation",
@@ -103,14 +119,14 @@ def median_ms(fn, *args, iters: int = 15) -> float:
 def provenance() -> dict:
     """The provenance block stamped into every BENCH_*.json row: enough
     to answer "what produced this number" when diffing the committed perf
-    trajectory across PRs.  Computed once per harness run
-    (``tools/check_docs.py`` rejects committed rows missing it)."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    trajectory across PRs (``tools/check_docs.py`` rejects committed rows
+    missing it).  Called in the process that measured, after it
+    measured: calling it initialises a JAX backend."""
 
     def git(*args: str) -> str:
         try:
             out = subprocess.run(["git", *args], capture_output=True,
-                                 text=True, cwd=root, timeout=10)
+                                 text=True, cwd=ROOT, timeout=10)
             return out.stdout.strip() if out.returncode == 0 else ""
         except OSError:
             return ""
@@ -138,8 +154,8 @@ class Reporter:
     def __init__(self, smoke: bool = False, trace_dir: str | None = None):
         self.smoke = smoke
         self.trace_dir = trace_dir
-        self.provenance = provenance()
-        self._groups: dict[str, list[dict]] = {}
+        self.provenance: dict | None = None    # set by the first row
+        self.groups: dict[str, list[dict]] = {}
 
     def __call__(self, name, value, notes=""):
         print(f"{name},{value},{notes}")
@@ -157,20 +173,59 @@ class Reporter:
             row["notes"] = notes
         if extra:
             row.update(extra)
+        if self.provenance is None:
+            self.provenance = provenance()
         row["provenance"] = self.provenance
-        self._groups.setdefault(group, []).append(row)
+        self.groups.setdefault(group, []).append(row)
         note = f"{row.get('events_per_s', '')} ev/s {notes}".strip()
         self(f"{group}/{op}/{shape}/median_ms", round(med_ms, 4), note)
 
-    def dump(self, out_dir: str):
-        log = obs_log.get_logger(__name__)
-        os.makedirs(out_dir, exist_ok=True)
-        for group, rows in self._groups.items():
-            path = os.path.join(out_dir, f"BENCH_{group}.json")
-            with open(path, "w") as f:
-                json.dump(rows, f, indent=1)
-                f.write("\n")
-            log.info("wrote %s (%d rows)", path, len(rows))
+    def run_script(self, script: str, params: dict, timeout: int
+                   ) -> list[dict]:
+        """Run a module's measuring ``script`` (which prints its rows as
+        one ``BENCH_JSON`` line) in a process of its own, with ``params``
+        as its argument; its provenance then stamps this module's rows."""
+        env = dict(os.environ)
+        env["PYTHONPATH"] = os.pathsep.join(
+            [os.path.join(ROOT, "src"), ROOT, env.get("PYTHONPATH", "")])
+        out = subprocess.run(
+            [sys.executable, "-c", script + _PROVENANCE_TAIL,
+             json.dumps(params)],
+            capture_output=True, text=True, timeout=timeout, env=env)
+        if out.returncode != 0:
+            raise RuntimeError(f"measuring script failed:\n{out.stdout}\n"
+                               f"{out.stderr}")
+        tagged = {}
+        for line in out.stdout.splitlines():
+            tag, _, rest = line.partition(" ")
+            if tag in ("BENCH_JSON", "BENCH_PROVENANCE"):
+                tagged[tag] = json.loads(rest)
+        self.provenance = tagged["BENCH_PROVENANCE"]
+        return tagged["BENCH_JSON"]
+
+
+def dump(groups: dict[str, list[dict]], out_dir: str):
+    log = obs_log.get_logger(__name__)
+    os.makedirs(out_dir, exist_ok=True)
+    for group, rows in groups.items():
+        path = os.path.join(out_dir, f"BENCH_{group}.json")
+        with open(path, "w") as f:
+            json.dump(rows, f, indent=1)
+            f.write("\n")
+        log.info("wrote %s (%d rows)", path, len(rows))
+
+
+def _run_module(mod_name: str, smoke: bool, trace_dir: str | None,
+                quiet: bool, verbose: bool) -> dict[str, list[dict]]:
+    """One module, in a process of its own: the harness's own process
+    never initialises JAX, so a module may start JAX processes."""
+    obs_log.setup_logging("INFO", quiet=quiet, verbose=verbose)
+    report = Reporter(smoke=smoke, trace_dir=trace_dir)
+    mod = __import__(f"benchmarks.{mod_name}", fromlist=["main"])
+    t0 = time.perf_counter()
+    mod.main(report)
+    report(f"{mod_name}/_wall_s", round(time.perf_counter() - t0, 1))
+    return report.groups
 
 
 def main() -> None:
@@ -195,22 +250,24 @@ def main() -> None:
     # on stderr; stdout carries only the CSV / BENCH_JSON protocols
     obs_log.setup_logging("INFO", quiet=args.quiet, verbose=args.verbose)
 
-    report = Reporter(smoke=args.smoke,
-                      trace_dir=args.out_dir if args.trace else None)
+    trace_dir = args.out_dir if args.trace else None
     modules = SMOKE_MODULES if args.smoke else MODULES
 
     print("name,value,notes")
-    report("env/tuned", int(os.environ.get("REPRO_BENCH_ENV", "0") != "0"),
-           "1 when tools/env.sh was sourced (tcmalloc, OMP pinning, "
-           "XLA step markers)")
+    print("env/tuned,%d,1 when tools/env.sh was sourced (tcmalloc, OMP "
+          "pinning, XLA step markers)"
+          % (os.environ.get("REPRO_BENCH_ENV", "0") != "0"), flush=True)
+    groups: dict[str, list[dict]] = {}
+    spawn = multiprocessing.get_context("spawn")
     for mod_name in modules:
         if args.only and args.only not in mod_name:
             continue
-        mod = __import__(f"benchmarks.{mod_name}", fromlist=["main"])
-        t0 = time.perf_counter()
-        mod.main(report)
-        report(f"{mod_name}/_wall_s", round(time.perf_counter() - t0, 1))
-    report.dump(args.out_dir)
+        with ProcessPoolExecutor(1, mp_context=spawn) as pool:
+            got = pool.submit(_run_module, mod_name, args.smoke, trace_dir,
+                              args.quiet, args.verbose).result()
+        for group, rows in got.items():
+            groups.setdefault(group, []).extend(rows)
+    dump(groups, args.out_dir)
 
 
 if __name__ == "__main__":

@@ -1,17 +1,19 @@
 """End-to-end driver: the paper's target workload — a (reduced-scale)
-Potjans-Diesmann cortical microcircuit spread over 4 'wafer' shards, spikes
-exchanged through the bucket-aggregated transport fabric.
+Potjans-Diesmann cortical microcircuit spread over one 'wafer' shard per
+device, spikes exchanged through the bucket-aggregated transport fabric.
 
 Prints per-window communication stats (events, wire bytes, aggregation
 efficiency, deadline misses) — the numbers the Extoll link budget cares
 about — plus per-population firing rates.
 
-NOTE: must run as its own process (forces 4 host devices).
+NOTE: must run as its own process.  On a CPU it forces 4 host devices, so
+it runs 4 shards there; on an accelerator it runs one shard per chip.
 Run:  PYTHONPATH=src python examples/multiwafer_microcircuit.py \
           [alltoall|torus2d|torus3d] [extoll|ethernet]
 (first arg selects the transport backend; default "alltoall".  "torus2d"
-walks dimension-ordered neighbor hops on a 2x2 device torus, "torus3d" on
-a 1x2x2 torus whose Z rings are the wafer-stacking axis; both report the
+walks dimension-ordered neighbor hops on a most-square device torus (2x2
+on 4 shards), "torus3d" on a most-cubic one (1x2x2) whose Z rings are the
+wafer-stacking axis; both report the
 link-level hop/forwarding stats with hop-by-hop credit flow control
 available via the config's link_credits.  Second arg selects the wire
 protocol profile (repro.wire): frame-exact bytes_on_wire and the
@@ -20,6 +22,7 @@ per-event latency percentiles are reported for it — run once with
 switch-latency comparison.)
 """
 import os
+# the flag shapes only the CPU backend: an accelerator keeps its own devices
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 
 import dataclasses
@@ -41,26 +44,27 @@ def main(transport: str = "alltoall", wire_format: str = "extoll"):
     print(f"microcircuit: {spec.n_neurons} neurons, "
           f"{(w != 0).sum()} synapses (scale={spec.scale})")
 
-    part = network.build_partition(w, is_inh, n_shards=4)
-    print(f"partition: 4 wafer shards x {part.per_shard} neurons, "
+    n_shards = jax.device_count()
+    part = network.build_partition(w, is_inh, n_shards=n_shards)
+    print(f"partition: {n_shards} wafer shards x {part.per_shard} neurons, "
           f"max fan-out {part.fanout.shape[1]} shards/source")
 
     bs = dataclasses.replace(brainscales.CONFIG, transport=transport,
                              wire_format=wire_format)
     cfg = sim.SimConfig(
-        n_shards=4, per_shard=part.per_shard,
+        n_shards=n_shards, per_shard=part.per_shard,
         max_fan=part.fanout.shape[1],
         window=8,                  # <= min axonal delay (deadline flush)
         ring_len=32, e_max=512, capacity=512,
         **bs.transport_fields(),
     )
     if transport == "torus2d":
-        print(f"transport: {transport} {wafer_torus_shape(4)} torus")
+        print(f"transport: {transport} {wafer_torus_shape(n_shards)} torus")
     elif transport == "torus3d":
-        print(f"transport: {transport} {wafer_torus_shape(4, ndim=3)} torus")
+        print(f"transport: {transport} {wafer_torus_shape(n_shards, ndim=3)} torus")
     else:
         print(f"transport: {transport}")
-    mesh = make_wafer_mesh(4)
+    mesh = make_wafer_mesh(n_shards)
     init, run = sim.build_sharded_sim(mesh, "wafer", cfg, part,
                                       spec.bg_rates())
     state = init(seed=0)

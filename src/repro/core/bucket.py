@@ -31,12 +31,14 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import events as ev
 
-NO_BUCKET = jnp.int32(-1)
-NO_DEST = jnp.int32(-1)
-_BIG = jnp.int32(1 << 20)
+# numpy scalars: see events.INVALID_EVENT
+NO_BUCKET = np.int32(-1)
+NO_DEST = np.int32(-1)
+_BIG = np.int32(1 << 20)
 
 
 class BucketConfig(NamedTuple):

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # --- wire-format constants (faithful to the paper) ----------------------
 TS_BITS = 15
@@ -46,7 +47,9 @@ PACKET_HEADER_BYTES = 16
 DATAPATH_BYTES_PER_CYCLE = 16                # FPGA->link datapath width
 DESERIAL_GROUP = 4                           # events per network word
 
-INVALID_EVENT = jnp.uint32(0)                # valid bit clear
+# host-side scalars (numpy, not jnp): importing the package must not
+# start a JAX backend, or a process could not hand the chip to a child
+INVALID_EVENT = np.uint32(0)                 # valid bit clear
 
 
 def pack(address: jax.Array, timestamp: jax.Array, valid=None) -> jax.Array:

@@ -197,8 +197,6 @@ def make_exchange(mesh, axis_name: str, *, n_shards: int, capacity: int,
     fresh each call (one-shot window; thread ``exchange_window`` manually
     for multi-window credit dynamics).
     """
-    from jax.experimental.shard_map import shard_map
-
     transport_opts = dict(transport_opts or {})
     transport_opts.setdefault("wire_format", wire_format)
     if transport in ("torus2d", "torus3d"):
@@ -216,14 +214,14 @@ def make_exchange(mesh, axis_name: str, *, n_shards: int, capacity: int,
         )
 
     spec = P(axis_name)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda w, d, g, m: jax.tree_util.tree_map(
             lambda x: x[None], body(w, d, g, m)
         ),
         mesh=mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit

@@ -25,7 +25,7 @@ from repro.core import events as ev
 
 DEST_BITS = 16          # Extoll: 16-bit destination address in the header
 MAX_DESTS = 1 << DEST_BITS
-NO_ROUTE = jnp.int32(-1)
+NO_ROUTE = np.int32(-1)          # numpy: see events.INVALID_EVENT
 
 
 @jax.tree_util.register_pytree_node_class

@@ -65,15 +65,14 @@ def make_compressed_allreduce(mesh, axis_names=("pod",)):
     int8.  This mirrors the paper's economy: compress what crosses the
     expensive fabric.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def one(g, e):
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(compressed_psum, axis_names=axis_names),
             mesh=mesh,
             in_specs=(P(), P()), out_specs=(P(), P()),
-            check_rep=False)
+            check_vma=False)
         return fn(g, e)
 
     def apply(grads, errs):

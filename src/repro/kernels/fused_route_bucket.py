@@ -13,19 +13,20 @@ module replaces the compute side with a sort-based formulation:
                    offset from the first event of its destination
   3. **place**   — each destination's bucket row is a *dynamic slice* of
                    the sorted window (O(D·C) total, no scatter); the
-                   destination-GUID lookup (LUT 1's second output) is fused
-                   into placement so only the ≤ C accepted events per
+                   destination-GUID lookup (LUT 1's second output) runs
+                   after placement, so only the ≤ C accepted events per
                    destination are gathered, not all N
   4. **residue** — events beyond a bucket's capacity are compacted into a
                    fixed-size carry buffer re-offered next window (the
                    FPGA's back-pressure on the HICANN links)
 
-Stage 3 is a Pallas TPU kernel (grid over destination tiles, per-row
-``pl.ds`` loads from the VMEM-resident sorted window, in-kernel guid-LUT
-gather).  Backend dispatch is automatic (``kernels.dispatch``): compiled
-Pallas on TPU, pure-XLA placement on CPU/GPU where interpret mode would be
-a correctness tool rather than a fast path; tests exercise the interpret
-path explicitly against the ``ref.py`` oracle.
+Stage 3 is a Pallas TPU kernel (grid over destination tiles; each row is
+read from the VMEM-resident sorted window as whole (8, 128) tiles and
+rotated into place, see ``_shift_rows``).  Backend dispatch is automatic
+(``kernels.dispatch``): compiled Pallas on TPU, pure-XLA placement on
+CPU/GPU where interpret mode would be a correctness tool rather than a
+fast path; tests exercise the interpret path explicitly against the
+``ref.py`` oracle.
 
 The destination gather (stage 1) stays in XLA because it *produces the sort
 key*; fusing it into the placement kernel would force the sort inside the
@@ -40,12 +41,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import events as ev
 from repro.core.aggregator import Buckets
 from repro.kernels import dispatch
 
-D_TILE = 8
+D_TILE = 8                        # destinations per grid step
+LANES, SUBLANES = 128, 8
+TILE = LANES * SUBLANES           # one (8, 128) u32 vreg
 
 
 class FusedWindow(NamedTuple):
@@ -71,92 +75,84 @@ class FusedWindow(NamedTuple):
 
 
 # ---------------------------------------------------------------------------
-# Pallas placement kernels — stage 3.
+# Pallas placement kernel — stage 3.
 # ---------------------------------------------------------------------------
+#
+# A destination's row starts at an arbitrary offset of the sorted window,
+# and Mosaic only loads vectors at offsets it can prove tile-aligned.  So
+# the window is laid out as (tiles, 8, 128) — one (8, 128) vreg tile per
+# leading index, which a dynamic index may address freely — and each row
+# loads the N_T whole tiles that cover it, then shifts the wanted run to
+# the front with dynamic sublane/lane rotations.
 
-def _row(words_ref, start, capacity):
-    return words_ref[pl.ds(start, capacity)].reshape(1, capacity)
-
-
-def _place_kernel(first_ref, counts_ref, words_ref, guids_ref,
-                  data_ref, gout_ref, *, capacity: int, d_tile: int):
-    """Explicit per-event guids travelled through the sort with the words."""
-    slot = lax.broadcasted_iota(jnp.int32, (1, capacity), 1)
-    for d in range(d_tile):
-        start = first_ref[d]
-        live = slot < jnp.minimum(counts_ref[d], capacity)
-        w = _row(words_ref, start, capacity)
-        g = _row(guids_ref, start, capacity)
-        data_ref[d, :] = jnp.where(live, w, jnp.uint32(0)).reshape(capacity)
-        gout_ref[d, :] = jnp.where(live, g, 0).reshape(capacity)
-
-
-def _place_route_kernel(first_ref, counts_ref, words_ref, lut_ref,
-                        data_ref, gout_ref, *, capacity: int, d_tile: int):
-    """Guid-LUT variant: the LUT gather happens *inside* the kernel and only
-    touches the ≤ capacity accepted events of each destination row."""
-    slot = lax.broadcasted_iota(jnp.int32, (1, capacity), 1)
-    n_lut = lut_ref.shape[0]
-    for d in range(d_tile):
-        start = first_ref[d]
-        live = slot < jnp.minimum(counts_ref[d], capacity)
-        w = jnp.where(live, _row(words_ref, start, capacity), jnp.uint32(0))
-        addr = ((w >> ev.TS_BITS) & ev.ADDR_MASK).astype(jnp.int32)
-        g = jnp.take(lut_ref[...], jnp.minimum(addr, n_lut - 1).reshape(capacity))
-        data_ref[d, :] = w.reshape(capacity)
-        gout_ref[d, :] = jnp.where(live.reshape(capacity), g, 0)
+def _shift_rows(x, k):
+    """Row-major ``flat(x)[k:]`` laid back onto ``x``'s (rows, 128) shape
+    (the tail wraps; callers keep only rows the shift filled)."""
+    rows = x.shape[0]
+    q, r = k // LANES, k % LANES
+    a = pltpu.roll(x, (rows - q) % rows, 0)         # a[j] = x[j + q]
+    b = pltpu.roll(a, (LANES - r) % LANES, 1)       # b[j, c] = a[j, c + r]
+    c = pltpu.roll(b, rows - 1, 0)                  # c[j] = b[j + 1]
+    col = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(col < LANES - r, b, c)
 
 
-def _placement_pallas(first, counts, swords_pad, aux, n_dest: int,
-                      capacity: int, *, routed: bool, interpret: bool):
-    """Launch the placement kernel over ceil(n_dest / D_TILE) dest tiles."""
+def _place_kernel(first_ref, counts_ref, *refs, capacity: int, n_t: int):
+    n_arr = len(refs) // 2
+    rows_out = refs[n_arr].shape[1]
+    base = pl.program_id(0) * D_TILE
+    shape = (rows_out, LANES)
+    slot = (lax.broadcasted_iota(jnp.int32, shape, 0) * LANES
+            + lax.broadcasted_iota(jnp.int32, shape, 1))
+    for d in range(D_TILE):
+        start = first_ref[base + d]
+        live = slot < jnp.minimum(counts_ref[base + d], capacity)
+        t0, k = start // TILE, start % TILE
+        for src, dst in zip(refs[:n_arr], refs[n_arr:]):
+            x = src[pl.ds(t0, n_t)].reshape(n_t * SUBLANES, LANES)
+            y = _shift_rows(x, k)[:rows_out]
+            dst[d] = jnp.where(live, y, jnp.zeros_like(y))
+
+
+def _placement_pallas(first, counts, arrays, n_dest: int, capacity: int,
+                      *, interpret: bool):
+    """Place each of ``arrays`` (sorted window operands) into (n_dest,
+    capacity) rows; a grid step fills D_TILE destinations."""
     d_pad = -(-n_dest // D_TILE) * D_TILE
     first = jnp.pad(first, (0, d_pad - n_dest))
     counts = jnp.pad(counts, (0, d_pad - n_dest))
-    n_pad = swords_pad.shape[0]
-    kernel = functools.partial(
-        _place_route_kernel if routed else _place_kernel,
-        capacity=capacity, d_tile=D_TILE)
-    tile = lambda i: (i,)
-    full = lambda i: (0,)
-    data, gout = pl.pallas_call(
-        kernel,
-        grid=(d_pad // D_TILE,),
-        in_specs=[
-            pl.BlockSpec((D_TILE,), tile),
-            pl.BlockSpec((D_TILE,), tile),
-            pl.BlockSpec((n_pad,), full),
-            pl.BlockSpec((aux.shape[0],), full),
-        ],
-        out_specs=(
-            pl.BlockSpec((D_TILE, capacity), lambda i: (i, 0)),
-            pl.BlockSpec((D_TILE, capacity), lambda i: (i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((d_pad, capacity), jnp.uint32),
-            jax.ShapeDtypeStruct((d_pad, capacity), jnp.int32),
-        ),
+    c_pad = -(-capacity // LANES) * LANES
+    rows_out = c_pad // LANES
+    n_t = -(-(c_pad + TILE - 1) // TILE)            # tiles one row can span
+    n = arrays[0].shape[0]
+    tiles = -(-n // TILE) + n_t                     # start <= n: t0+n_t fits
+    arrays = [jnp.pad(a, (0, tiles * TILE - n)).reshape(tiles, SUBLANES,
+                                                        LANES)
+              for a in arrays]
+    whole = pl.BlockSpec((tiles, SUBLANES, LANES), lambda i, f, c: (0, 0, 0))
+    rows = pl.BlockSpec((D_TILE, rows_out, LANES), lambda i, f, c: (i, 0, 0))
+    outs = pl.pallas_call(
+        functools.partial(_place_kernel, capacity=capacity, n_t=n_t),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(d_pad // D_TILE,),
+            in_specs=[whole] * len(arrays), out_specs=[rows] * len(arrays)),
+        out_shape=[jax.ShapeDtypeStruct((d_pad, rows_out, LANES), a.dtype)
+                   for a in arrays],
         interpret=interpret,
-    )(first, counts, swords_pad, aux)
-    return data[:n_dest], gout[:n_dest]
+    )(first, counts, *arrays)
+    return [o.reshape(d_pad, c_pad)[:n_dest, :capacity] for o in outs]
 
 
 # ---------------------------------------------------------------------------
 # XLA placement — same math, used where Pallas would only interpret.
 # ---------------------------------------------------------------------------
 
-def _placement_jnp(first, counts, swords_pad, aux, n_dest: int, capacity: int,
-                   *, routed: bool):
+def _placement_jnp(first, counts, arrays, n_dest: int, capacity: int):
     slot = jnp.arange(capacity)[None, :]
     live = slot < jnp.minimum(counts, capacity)[:, None]
-    idx = first[:, None] + slot                      # swords_pad absorbs idx<=n+C
-    data = jnp.where(live, swords_pad[idx], jnp.uint32(0))
-    if routed:
-        addr = ev.address(data).astype(jnp.int32)
-        g = jnp.take(aux, jnp.minimum(addr, aux.shape[0] - 1))
-    else:
-        g = aux[idx]
-    return data, jnp.where(live, g, 0)
+    idx = first[:, None] + slot                     # <= n + capacity - 1
+    return [jnp.where(live, jnp.pad(a, (0, capacity))[idx],
+                      jnp.zeros((), a.dtype)) for a in arrays]
 
 
 # ---------------------------------------------------------------------------
@@ -170,8 +166,6 @@ def _finish(skey, swords, aux, n_dest: int, capacity: int, residue_len: int,
     edges = jnp.searchsorted(skey, jnp.arange(n_dest + 1, dtype=skey.dtype))
     first = edges[:-1].astype(jnp.int32)
     counts = (edges[1:] - edges[:-1]).astype(jnp.int32)
-    swords_pad = jnp.concatenate(
-        [swords, jnp.full((capacity,), ev.INVALID_EVENT)])
     if use_pallas is None:
         use_pallas = dispatch.use_pallas()
     if interpret is None:
@@ -180,16 +174,23 @@ def _finish(skey, swords, aux, n_dest: int, capacity: int, residue_len: int,
         raise ValueError("with_residue_meta needs per-event meta (the "
                          "explicit-guids path), not a routed guid LUT")
     smeta = aux if not routed else None          # (n,) sorted per-event meta
-    if not routed:
-        aux = jnp.concatenate([aux, jnp.zeros((capacity,), aux.dtype)])
+    operands = [swords] if routed else [swords, aux]
     if use_pallas:
-        data, gui = _placement_pallas(first, counts, swords_pad, aux, n_dest,
-                                      capacity, routed=routed,
-                                      interpret=interpret)
+        placed = _placement_pallas(first, counts, operands, n_dest, capacity,
+                                   interpret=interpret)
     else:
-        data, gui = _placement_jnp(first, counts, swords_pad, aux, n_dest,
-                                   capacity, routed=routed)
+        placed = _placement_jnp(first, counts, operands, n_dest, capacity)
     accepted = jnp.minimum(counts, capacity)
+    data = placed[0]
+    if routed:
+        # LUT 1's guid output, gathered for the <= capacity accepted
+        # events of each row only
+        live = jnp.arange(capacity)[None, :] < accepted[:, None]
+        addr = ev.address(data).astype(jnp.int32)
+        g = jnp.take(aux, jnp.minimum(addr, aux.shape[0] - 1))
+        gui = jnp.where(live, g, 0)
+    else:
+        gui = placed[1]
     offered = jnp.sum(counts).astype(jnp.int32)
     overflow = (offered - jnp.sum(accepted)).astype(jnp.int32)
     buckets = Buckets(data, gui, accepted, overflow)
@@ -261,8 +262,7 @@ def fused_route_aggregate(words, dest_lut, guid_lut, n_dest: int,
 
     ``dest_lut``/``guid_lut`` are ``RoutingTables.dest_of_addr`` /
     ``.guid_of_addr`` (same clamped-index semantics as ``tables.route``).
-    The guid gather runs inside the placement kernel over accepted events
-    only.
+    The guid gather runs after placement, over accepted events only.
     """
     addr = ev.address(words).astype(jnp.int32)
     dest = jnp.take(dest_lut, jnp.minimum(addr, dest_lut.shape[0] - 1))

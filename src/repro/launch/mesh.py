@@ -19,30 +19,41 @@ touch jax device state (the dry-run sets XLA_FLAGS before first jax init).
 """
 from __future__ import annotations
 
+import math
+
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple, axes: tuple, devices=None):
+    """The one mesh constructor: the first ``prod(shape)`` devices (or
+    ``devices``) on named axes of type ``Auto``.
+
+    The axis types are spelled out because ``jax.make_mesh``'s default
+    changed to ``Explicit``, under which host-side integer indexing of a
+    sharded array and ``with_sharding_constraint`` both raise.
+    """
+    n = math.prod(shape)
+    if devices is None:
+        devices = jax.devices()[:n]
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_test_mesh(n_data: int = 2, n_model: int = 4, pods: int = 0):
-    """Small mesh for CPU tests (requires forced host device count)."""
-    if pods:
-        return jax.make_mesh((pods, n_data, n_model),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh(shape, axes)
 
 
 def batch_axes(mesh) -> tuple:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
-def make_wafer_mesh(n_shards: int, axis: str = "wafer"):
+def make_wafer_mesh(n_shards: int, axis: str = "wafer", devices=None):
     """1-D mesh for the spike-exchange fabric (one device per shard)."""
-    return jax.make_mesh((n_shards,), (axis,))
+    return make_mesh((n_shards,), (axis,), devices)
 
 
 def wafer_torus_shape(n_shards: int, ndim: int = 2) -> tuple:

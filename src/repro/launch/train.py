@@ -71,7 +71,8 @@ def main():
     rt = Runtime()
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((d, m), ("data", "model"))
         rt = Runtime(mesh=mesh, batch_axes=batch_axes(mesh),
                      moe_impl=args.moe_impl, remat=True)
 

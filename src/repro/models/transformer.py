@@ -253,21 +253,19 @@ def _split_kv_decode(q, cache, rt: Runtime, scale, window, softcap):
     per-layer cache all-gather with one tiny logsumexp-combine psum)."""
     from functools import partial as _partial
 
-    from jax.experimental.shard_map import shard_map
-
     from repro.distributed.collectives import split_kv_decode_attention
 
     ax = rt.split_kv_axis
     bspec = P(rt.batch_axes, None, None, None)
     kspec = P(rt.batch_axes, ax, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q_, k_, v_, ln_, w_: split_kv_decode_attention(
             q_, k_, v_, ln_, axis_name=ax, scale=scale if scale else None,
             softcap=softcap, window=w_),
         mesh=rt.mesh,
         in_specs=(bspec, kspec, kspec, P(), P()),
         out_specs=bspec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, cache.k, cache.v, cache.length, jnp.asarray(window))
 
@@ -312,8 +310,6 @@ def moe_block(p, x, cfg: ModelConfig, rt: Runtime):
 
 def _moe_bucket_sharded(flat, mp, cfg: ModelConfig, rt: Runtime, B, S):
     """shard_map EP dispatch (paper's bucket aggregation over the ICI)."""
-    from jax.experimental.shard_map import shard_map
-
     d = flat.shape[-1]
     x3 = flat.reshape(B, S, d)
     # tokens enter the dispatch sequence-sharded over the EP axis: each
@@ -337,11 +333,11 @@ def _moe_bucket_sharded(flat, mp, cfg: ModelConfig, rt: Runtime, B, S):
             lambda s: jax.lax.pmean(s, rt.model_axis), stats)
         return y.reshape(xl.shape), stats
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=rt.mesh,
         in_specs=(bspec, P(), espec, espec, espec),
         out_specs=(bspec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     y, stats = fn(x3, mp["router"],
                   mp["w_gate"], mp["w_up"], mp["w_down"])

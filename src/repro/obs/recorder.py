@@ -165,9 +165,11 @@ def ring_shard(ring: TelemetryRing, s: int = 0) -> TelemetryRing:
     The descriptor lanes (credits, parked_by_link, stalled_by_link) are
     replicated global state, so any shard's view is THE view; the counter
     lanes are per-shard and callers wanting global totals sum them across
-    shards before (or instead of) picking one.
+    shards before (or instead of) picking one.  The pick happens on the
+    host: a Python-int index into a ``wafer``-sharded device array is
+    refused under explicitly typed mesh axes.
     """
-    return jax.tree_util.tree_map(lambda a: a[s], ring)
+    return jax.tree_util.tree_map(lambda a: np.asarray(a)[s], ring)
 
 
 def ring_rows(ring: TelemetryRing) -> list[dict]:

@@ -40,8 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.fabric import faults as fabric_faults
 from repro.obs import recorder as obs_recorder
@@ -271,26 +270,29 @@ class SpikeEngine:
 
         spec = P(ax)
         n_carry = 5 if rec else 4
-        self._seg = jax.jit(shard_map(
+        self._seg = jax.jit(jax.shard_map(
             seg_fn, mesh=self.mesh,
             in_specs=(spec,) * n_carry + (spec, spec, P()),
-            out_specs=(spec, spec), check_rep=False))
-        self._drain_walk = jax.jit(shard_map(
+            out_specs=(spec, spec), check_vma=False))
+        self._drain_walk = jax.jit(jax.shard_map(
             drain_fn, mesh=self.mesh,
             in_specs=(spec, spec, spec, spec, P()),
-            out_specs=(spec, spec), check_rep=False))
+            out_specs=(spec, spec), check_vma=False))
 
     # -- runtime state -----------------------------------------------------
     def _reset_runtime(self):
         S, T, C = self.n_shards, self.n_tenants, self.cfg.capacity
         nw, depth = self.cfg.seg_windows, self.cfg.queue_depth
         W = 2 * C                        # planar wire words per row
+        # every per-shard operand lives on its own shard's device
+        self._shard = NamedSharding(self.mesh, P(self.axis_name))
+        put = lambda t: jax.device_put(t, self._shard)
         state0 = self.transport.init_state(W)
         bcast = lambda a: jnp.broadcast_to(a[None], (S,) + a.shape)
-        self._carry = (jax.tree.map(bcast, state0),
-                       jnp.zeros((S, T, S, C), jnp.uint32),
-                       jnp.zeros((S, T, S, C), jnp.int32),
-                       jnp.zeros((S, T, S), jnp.int32))
+        self._carry = put((jax.tree.map(bcast, state0),
+                           jnp.zeros((S, T, S, C), jnp.uint32),
+                           jnp.zeros((S, T, S, C), jnp.int32),
+                           jnp.zeros((S, T, S), jnp.int32)))
         if self.recorder is not None:
             # the flight-recorder ring rides as the 5th carry element;
             # credit lanes carry partition slots ((T+1)*K), the stall
@@ -299,21 +301,21 @@ class SpikeEngine:
                 self.recorder.depth, state0, (T,),
                 (T, wire_latency.N_LATENCY_BINS),
                 S * self.transport.n_links)
-            self._carry = self._carry + (jax.tree.map(bcast, ring0),)
+            self._carry = self._carry + (put(jax.tree.map(bcast, ring0)),)
         # pinned staging pair: preallocated, filled in place by the
-        # ingestion thread, handed to the device via jnp.asarray (the
-        # host->device copy; on accelerators device_put from these fixed
-        # host buffers is the pinned-staging path)
+        # ingestion thread, handed to the devices by device_put (the
+        # host->device copy from these fixed host buffers)
         self._words_buf = np.zeros((depth, S, nw, T, S, C), np.uint32)
         self._counts_buf = np.zeros((depth, S, nw, T, S), np.int32)
-        self._zero_fw = jnp.zeros((S, nw, T, S, C), jnp.uint32)
-        self._zero_fc = jnp.zeros((S, nw, T, S), jnp.int32)
+        self._zero_fw = put(jnp.zeros((S, nw, T, S, C), jnp.uint32))
+        self._zero_fc = put(jnp.zeros((S, nw, T, S), jnp.int32))
         self._free_q: queue.Queue = queue.Queue()
         for i in range(depth):
             self._free_q.put(i)
         self._staged_q: queue.Queue = queue.Queue(maxsize=depth)
         self._stop_evt = threading.Event()
         self._ingest_t = self._device_t = None
+        self._errors: list[Exception] = []     # host-thread failures
         self._max_segments = None
         self._win = 0
         self._windows = 0
@@ -356,39 +358,51 @@ class SpikeEngine:
                 inj, clip = self._fill_segment(slot, seg)
                 self._staged_q.put((slot, inj, clip))
                 seg += 1
+        except Exception as e:           # re-raised by stop()
+            self._errors.append(e)
         finally:
             self._staged_q.put(None)
 
     def _device_loop(self):
         prev = None
-        while True:
-            with self.tracer.span("device/staged_wait",
-                                  track="spike-device"):
-                item = self._staged_q.get()
-            if item is None:
-                break
-            slot, inj, clip = item
-            # copy=True matters: zero-copy host->device aliasing would
-            # let the ingest thread overwrite the slot mid-read
-            with self.tracer.span("device/h2d", track="spike-device",
-                                  slot=slot):
-                fw = jnp.array(self._words_buf[slot], copy=True)
-                fc_ = jnp.array(self._counts_buf[slot], copy=True)
-            self._free_q.put(slot)       # staging slot reusable: the
-            #                              host->device copy is done
-            win0 = self._win
-            with self.tracer.span("device/dispatch", track="spike-device",
-                                  win0=win0):
-                self._carry, ws = self._seg(*self._carry, fw, fc_,
-                                            jnp.int32(self._win))
-            self._win += self.cfg.seg_windows
-            self._windows += self.cfg.seg_windows
-            self.ledger.add_injected(inj, clip)
-            if prev is not None:         # absorb k-1 while k runs
+        ended = False                    # the ingest thread's None seen
+        try:
+            while True:
+                with self.tracer.span("device/staged_wait",
+                                      track="spike-device"):
+                    item = self._staged_q.get()
+                if item is None:
+                    ended = True
+                    break
+                slot, inj, clip = item
+                # the host copy matters: device_put may alias the host
+                # buffer or read it after returning, and the ingest thread
+                # refills the slot as soon as it is freed below
+                with self.tracer.span("device/h2d", track="spike-device",
+                                      slot=slot):
+                    fw, fc_ = jax.device_put(
+                        (self._words_buf[slot].copy(),
+                         self._counts_buf[slot].copy()), self._shard)
+                self._free_q.put(slot)   # staging slot reusable: the
+                #                          host->device copy is done
+                win0 = self._win
+                with self.tracer.span("device/dispatch",
+                                      track="spike-device", win0=win0):
+                    self._carry, ws = self._seg(*self._carry, fw, fc_,
+                                                jnp.int32(self._win))
+                self._win += self.cfg.seg_windows
+                self._windows += self.cfg.seg_windows
+                self.ledger.add_injected(inj, clip)
+                if prev is not None:     # absorb k-1 while k runs
+                    self._absorb(*prev)
+                prev = (ws, win0)
+            if prev is not None:
                 self._absorb(*prev)
-            prev = (ws, win0)
-        if prev is not None:
-            self._absorb(*prev)
+        except Exception as e:           # e.g. a compile error; re-raised
+            self._errors.append(e)       # by stop()
+            self._stop_evt.set()
+            while not ended:             # unblock the ingest thread
+                ended = self._staged_q.get() is None
         self._t1 = self.tracer.now_us()
 
     def _absorb(self, ws: WindowServeStats, win0: int | None = None):
@@ -491,7 +505,10 @@ class SpikeEngine:
     def stop(self, drain: bool = True, timeout: float = 120.0
              ) -> EngineReport:
         """Graceful shutdown: stop ingestion, finish staged segments,
-        drain the fabric, verify per-tenant conservation, report."""
+        drain the fabric, verify per-tenant conservation, report.
+
+        An exception raised in the ingest or device thread (a source
+        error, a compile failure on the device) is re-raised here."""
         if self._ingest_t is None:
             raise RuntimeError("engine not started")
         self._stop_evt.set()
@@ -502,6 +519,11 @@ class SpikeEngine:
                                "(ingest alive=%s device alive=%s)" % (
                                    self._ingest_t.is_alive(),
                                    self._device_t.is_alive()))
+        if self._errors:
+            err = self._errors[0]
+            self._errors.clear()
+            self._ingest_t = self._device_t = None
+            raise err
         if drain:
             self._drain()
             self.ledger.check_conservation()

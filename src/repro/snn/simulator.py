@@ -51,7 +51,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import transport as tp
 from repro import wire
@@ -533,26 +533,28 @@ def build_sharded_segments(mesh, axis_name: str, cfg: SimConfig,
                                        exchange; no event is lost between
                                        segment end and shutdown
     """
-    from jax.experimental.shard_map import shard_map
-
     S, per = cfg.n_shards, cfg.per_shard
     n_tot = part.n_neurons
+    spec = P(axis_name)
+    # every stacked per-shard operand is placed once, shard s on device s
+    shard = NamedSharding(mesh, spec)
+    put = functools.partial(jax.device_put, device=shard)
     w_local, _fan, delay_local = network.shard_arrays(part)
     is_inh = part.is_inh
-    w_exc = jnp.asarray(np.where(~is_inh[None, :], w_local, 0.0).reshape(S, per, n_tot))
-    w_inh = jnp.asarray(np.where(is_inh[None, :], w_local, 0.0).reshape(S, per, n_tot))
-    delays = jnp.asarray(delay_local)
+    w_exc = put(np.where(~is_inh[None, :], w_local, 0.0).astype(np.float32))
+    w_inh = put(np.where(is_inh[None, :], w_local, 0.0).astype(np.float32))
+    delays = put(delay_local)
     tabs = [network.routing_tables_for_shard(part, s) for s in range(S)]
     # pad per-shard tables to a common size before stacking
     na = max(t.dest_of_addr.shape[0] for t in tabs)
     ng = max(t.mcast_of_guid.shape[0] for t in tabs)
-    dest_t = jnp.stack([jnp.pad(t.dest_of_addr, (0, na - t.dest_of_addr.shape[0]),
-                                constant_values=-1) for t in tabs])
-    guid_t = jnp.stack([jnp.pad(t.guid_of_addr, (0, na - t.guid_of_addr.shape[0]))
-                        for t in tabs])
-    mcast_t = jnp.stack([jnp.pad(t.mcast_of_guid, (0, ng - t.mcast_of_guid.shape[0]))
-                         for t in tabs])
-    bg = jnp.asarray(np.pad(bg_rates, (0, n_tot - len(bg_rates))).reshape(S, per))
+    pad = lambda a, n, v=0: np.pad(np.asarray(a), (0, n - a.shape[0]),
+                                   constant_values=v)
+    dest_t = put(np.stack([pad(t.dest_of_addr, na, -1) for t in tabs]))
+    guid_t = put(np.stack([pad(t.guid_of_addr, na) for t in tabs]))
+    mcast_t = put(np.stack([pad(t.mcast_of_guid, ng) for t in tabs]))
+    bg = put(np.pad(bg_rates, (0, n_tot - len(bg_rates))).reshape(S, per)
+             .astype(np.float32))
 
     init_pending, init_link, body, drain, init_ring = make_pipeline_fns(
         cfg, axis_name=axis_name, fault_schedule=fault_schedule,
@@ -585,22 +587,25 @@ def build_sharded_segments(mesh, axis_name: str, cfg: SimConfig,
         return (jax.tree_util.tree_map(lambda x: x[None], st),
                 miss_d[None])
 
-    spec = P(axis_name)
-
     @functools.lru_cache(maxsize=None)
     def _compiled_segment(n_windows: int):
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(seg_fn, n_windows=n_windows),
             mesh=mesh, in_specs=(spec,) * 8, out_specs=(spec, spec),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(fn)
 
-    def run_segment(carry: SimCarry, n_windows: int):
-        return _compiled_segment(n_windows)(
-            carry, dest_t, guid_t, mcast_t, w_exc, w_inh, delays, bg)
+    operands = (dest_t, guid_t, mcast_t, w_exc, w_inh, delays, bg)
 
-    fin = jax.jit(shard_map(fin_fn, mesh=mesh, in_specs=(spec,) * 3,
-                            out_specs=(spec, spec), check_rep=False))
+    def run_segment(carry: SimCarry, n_windows: int):
+        return _compiled_segment(n_windows)(carry, *operands)
+
+    # the lowered segment, for checking what the compiler made of it
+    run_segment.lower = lambda carry, n_windows: _compiled_segment(
+        n_windows).lower(carry, *operands)
+
+    fin = jax.jit(jax.shard_map(fin_fn, mesh=mesh, in_specs=(spec,) * 3,
+                                out_specs=(spec, spec), check_vma=False))
 
     def finish(carry: SimCarry):
         return fin(carry, w_exc, w_inh)
@@ -617,11 +622,12 @@ def build_sharded_segments(mesh, axis_name: str, cfg: SimConfig,
         )
         # pending/link start identical on every shard: broadcast host-side
         bcast = lambda a: jnp.broadcast_to(a[None], (S,) + a.shape)
-        return SimCarry(state,
-                        jax.tree_util.tree_map(bcast, init_pending()),
-                        jax.tree_util.tree_map(bcast, init_link()),
-                        (jax.tree_util.tree_map(bcast, init_ring())
-                         if init_ring is not None else None))
+        carry = SimCarry(state,
+                         jax.tree_util.tree_map(bcast, init_pending()),
+                         jax.tree_util.tree_map(bcast, init_link()),
+                         (jax.tree_util.tree_map(bcast, init_ring())
+                          if init_ring is not None else None))
+        return jax.tree_util.tree_map(put, carry)
 
     return init, run_segment, finish
 
@@ -662,4 +668,6 @@ def build_sharded_sim(mesh, axis_name: str, cfg: SimConfig, part: network.Partit
             return state, stats, carry.ring
         return state, stats
 
+    run.lower = lambda state, n_windows: run_segment.lower(
+        SimCarry(state, fresh.pending, fresh.link, fresh.ring), n_windows)
     return init, run

@@ -104,6 +104,20 @@ class AdmissionOut(NamedTuple):
                                  #   (stall_attribution builds only; sums
                                  #   to the global deferred total)
 
+def hop_histogram(hop: jax.Array, weight: jax.Array,
+                  n_hops: int) -> jax.Array:
+    """Sum ``weight`` into bins ``clip(hop, 0, n_hops - 1)`` along the
+    last axis -> (..., n_hops) i32.
+
+    A one-hot sum, not a scatter-add: when ``hop`` and ``weight`` fold to
+    the same constant (an uncredited fabric never stalls), the TPU
+    compiler's scatter emitter aborts on the merged operands.
+    """
+    bins = jnp.clip(hop, 0, n_hops - 1)[..., None] == jnp.arange(n_hops)
+    return jnp.sum(jnp.where(bins, weight[..., None], 0),
+                   axis=-2).astype(jnp.int32)
+
+
 def default_shape(n_shards: int) -> tuple[int, int]:
     """Most-square (nx, ny) factorization with nx <= ny (8 -> (2, 4),
     matching the paper's 2x4 concentrator face per wafer)."""
@@ -978,9 +992,8 @@ class TorusTransport(base.Transport):
 
         # 3. stats: deferred rows histogrammed by their blocking hop,
         #    parked rows by the hop they wait at
-        stalled_by_hop = jnp.zeros((self.max_hops,), jnp.int32).at[
-            jnp.clip(stall_hop, 0, self.max_hops - 1)
-        ].add(jnp.where(stall_hop >= 0, counts, 0))
+        stalled_by_hop = hop_histogram(
+            stall_hop, jnp.where(stall_hop >= 0, counts, 0), self.max_hops)
         offered = jnp.sum(counts).astype(jnp.int32)
         if throttled:
             sent = jnp.sum(jnp.where(sent_now, counts, 0)).astype(jnp.int32)
@@ -988,8 +1001,7 @@ class TorusTransport(base.Transport):
             unparked = jnp.sum(
                 jnp.where(resumed, pc0_me, 0)).astype(jnp.int32)
             pk_cnt, pk_hop = state.parked_count[me], state.parked_hop[me]
-            parked_by_hop = jnp.zeros((self.max_hops,), jnp.int32).at[
-                jnp.clip(pk_hop, 0, self.max_hops - 1)].add(pk_cnt)
+            parked_by_hop = hop_histogram(pk_hop, pk_cnt, self.max_hops)
             # frame-exact bytes: each row pays one frame-train
             # re-serialization per link it crossed THIS window, so across
             # park/resume windows every route link is counted exactly once
@@ -1781,11 +1793,8 @@ class TenantTorusTransport(TorusTransport):
                 "in_flight_phase": [jnp.int32(0)] * ndim}
 
     def _by_hop(self, hop: jax.Array, weight: jax.Array) -> jax.Array:
-        """Scatter (T, n) weights into (T, max_hops) hop histograms."""
-        T, H = self.n_tenants, self.max_hops
-        return jnp.zeros((T, H), jnp.int32).at[
-            jnp.arange(T)[:, None], jnp.clip(hop, 0, H - 1)
-        ].add(weight)
+        """Sum (T, n) weights into (T, max_hops) hop histograms."""
+        return hop_histogram(hop, weight, self.max_hops)
 
     def _fabric_level(self, acc: dict):
         """Fabric-wide (non-decomposable) stats attributed to tenant 0 so

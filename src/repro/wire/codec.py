@@ -26,8 +26,8 @@ Pallas has no 64-bit integer lanes, so a wire word is represented as two
 straddle the lane boundary (the default layout puts meta at bit 29), so
 the codec is real 64-bit bit-packing, not a reshuffle.
 
-Pack/unpack run as a Pallas TPU kernel (elementwise VPU bit ops, tiled
-1-D grid) with the pure-XLA formulation of the same math auto-selected
+Pack/unpack run as a Pallas TPU kernel (elementwise VPU bit ops over
+(rows, 128) blocks) with the pure-XLA formulation of the same math auto-selected
 off-TPU via ``repro.kernels.dispatch`` — identical policy to the fused
 placement kernel.  Round-trip is bit-exact for every well-formed event
 word (reserved bits zero, see ``events.pack``) and any 32-bit meta value
@@ -46,7 +46,8 @@ from jax.experimental import pallas as pl
 from repro.core import events as ev
 from repro.kernels import dispatch
 
-L_TILE = 512                      # 1-D codec tile (events per grid step)
+LANES, SUBLANES = 128, 8          # one (8, 128) u32 vreg tile
+BLOCK_ROWS = 256                  # rows per grid step: 32K events
 
 _U32 = 0xFFFFFFFF
 
@@ -147,7 +148,7 @@ def _decode_math(lo, hi, fmt: WireWordFormat):
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernels — the same math over 1-D VMEM tiles.
+# Pallas kernels — the same math over (rows, 128) VMEM blocks.
 # ---------------------------------------------------------------------------
 
 def _encode_kernel(word_ref, meta_ref, lo_ref, hi_ref, *, fmt):
@@ -163,22 +164,25 @@ def _decode_kernel(lo_ref, hi_ref, word_ref, meta_ref, *, fmt):
 
 
 def _pallas_map2(kernel, a, b, fmt, interpret: bool):
-    """Run an elementwise 2-in/2-out codec kernel over flat uint32 arrays."""
+    """Run an elementwise 2-in/2-out codec kernel over flat uint32 arrays,
+    laid out as (rows, 128) so every block is whole (8, 128) tiles."""
     n = a.shape[0]
-    n_pad = max(-(-n // L_TILE) * L_TILE, L_TILE)
-    a = jnp.pad(a, (0, n_pad - n))
-    b = jnp.pad(b, (0, n_pad - n))
-    tile = lambda i: (i,)
+    rows = max(-(-n // (LANES * SUBLANES)), 1) * SUBLANES
+    block = min(rows, BLOCK_ROWS)
+    rows = -(-rows // block) * block
+    a, b = (jnp.pad(x, (0, rows * LANES - n)).reshape(rows, LANES)
+            for x in (a, b))
+    spec = pl.BlockSpec((block, LANES), lambda i: (i, 0))
     o1, o2 = pl.pallas_call(
         functools.partial(kernel, fmt=fmt),
-        grid=(n_pad // L_TILE,),
-        in_specs=[pl.BlockSpec((L_TILE,), tile), pl.BlockSpec((L_TILE,), tile)],
-        out_specs=(pl.BlockSpec((L_TILE,), tile), pl.BlockSpec((L_TILE,), tile)),
-        out_shape=(jax.ShapeDtypeStruct((n_pad,), jnp.uint32),
-                   jax.ShapeDtypeStruct((n_pad,), jnp.uint32)),
+        grid=(rows // block,),
+        in_specs=[spec, spec],
+        out_specs=(spec, spec),
+        out_shape=(jax.ShapeDtypeStruct((rows, LANES), jnp.uint32),
+                   jax.ShapeDtypeStruct((rows, LANES), jnp.uint32)),
         interpret=interpret,
     )(a, b)
-    return o1[:n], o2[:n]
+    return o1.reshape(-1)[:n], o2.reshape(-1)[:n]
 
 
 def _dispatch2(kernel, math_fn, a, b, fmt, use_pallas, interpret):

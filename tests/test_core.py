@@ -1,5 +1,7 @@
 """Core library tests: events, routing, bucket cycle model, aggregator,
 flow control, torus — including the paper's §3.1 throughput claims."""
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,7 @@ from repro.core import flow_control as fc
 from repro.core import routing as rt
 from repro.core import torus
 
+from md_helper import run_md
 from prop import draw, given
 
 
@@ -50,6 +53,23 @@ def test_packet_cost_paper_constants():
     assert int(ev.wire_cycles(124)) == 32       # 3.875 events/clock drained
     assert abs(float(ev.wire_efficiency(124)) - 496 / 512) < 1e-6
     assert int(ev.packet_bytes(0)) == 0
+
+
+def test_import_starts_no_jax_backend():
+    """Importing the package and the benchmark harness leaves JAX without
+    a backend: on a TPU only one process may hold the chip, so a process
+    that starts JAX children (``benchmarks.run``) must not have taken it
+    by importing a module-level device array."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    run_md(
+        "import importlib, pkgutil, sys\n"
+        f"sys.path.insert(0, {root!r})\n"
+        "import repro, benchmarks.run\n"
+        "for m in pkgutil.walk_packages(repro.__path__, 'repro.'):\n"
+        "    importlib.import_module(m.name)\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge.backends_are_initialized()\n",
+        n_devices=1)
 
 
 # ---------------------------------------------------------------------------

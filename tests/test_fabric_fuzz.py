@@ -71,13 +71,13 @@ sys.path.insert(0, {TESTS_DIR!r})
 import functools
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro import transport
 from repro.serve.loadgen import traffic_rng, draw_counts, draw_payload
 
 D, W, WINDOWS = 8, 6, 3
 SEEDS = 20
-mesh = jax.make_mesh((D,), ("wafer",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(D)
 spec = P("wafer")
 counts_of = lambda rng: draw_counts(rng, (D, D), 31)
 payload_of = lambda rng: draw_payload(rng, (D, D, W))
@@ -97,11 +97,11 @@ def make_fns(t):
         return jax.tree_util.tree_map(
             lambda x: x[None],
             (out.state, out.recv_payload, out.recv_counts, out.stats))
-    mk = lambda enforce: jax.jit(shard_map(
+    mk = lambda enforce: jax.jit(jax.shard_map(
         functools.partial(body, enforce=enforce), mesh=mesh,
-        in_specs=(spec, spec, spec), out_specs=spec, check_rep=False))
-    walk = jax.jit(shard_map(dbody, mesh=mesh, in_specs=(spec,),
-                             out_specs=spec, check_rep=False))
+        in_specs=(spec, spec, spec), out_specs=spec, check_vma=False))
+    walk = jax.jit(jax.shard_map(dbody, mesh=mesh, in_specs=(spec,),
+                                 out_specs=spec, check_vma=False))
     return mk(True), mk(False), walk
 
 def fuzz_case(fns, t, seed, zero_bank):
@@ -230,14 +230,14 @@ def test_fabric_chaos_fuzz():
 import functools
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro import transport
 from repro.fabric import chaos, mask_at
 from repro.serve.loadgen import traffic_rng, draw_counts, draw_payload
 
 D, W, WINDOWS = 8, 6, 6
 SEEDS = 5
-mesh = jax.make_mesh((D,), ("wafer",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(D)
 spec = P("wafer")
 
 def make_fns(t):
@@ -255,10 +255,10 @@ def make_fns(t):
         return jax.tree_util.tree_map(
             lambda x: x[None],
             (out.state, out.recv_payload, out.recv_counts, out.stats))
-    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                           out_specs=spec, check_rep=False))
-    walk = jax.jit(shard_map(dbody, mesh=mesh, in_specs=(spec,),
-                             out_specs=spec, check_rep=False))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                               out_specs=spec, check_vma=False))
+    walk = jax.jit(jax.shard_map(dbody, mesh=mesh, in_specs=(spec,),
+                                 out_specs=spec, check_vma=False))
     return fn, walk
 
 def chaos_case(fns, t, dims, seed):
@@ -387,7 +387,8 @@ from repro.snn import microcircuit as mc, network, simulator as sim
 spec = mc.MicrocircuitSpec(scale=0.003)
 w, is_inh = spec.weight_matrix()
 part = network.build_partition(w, is_inh, n_shards=4)
-mesh = jax.make_mesh((4,), ("wafer",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(4)
 
 for transport, kw in [("torus2d", {}),
                       ("torus3d", dict(torus_nx=1, torus_ny=2,
@@ -432,7 +433,8 @@ from repro import wire
 from repro.core import events as ev, routing as rt
 from repro.core.exchange import make_exchange
 n_shards, N, C, n_addr = 8, 64, 16, 96
-mesh = jax.make_mesh((n_shards,), ("wafer",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(n_shards)
 tabs = []
 for s in range(n_shards):
     projs = [rt.Projection(a, a+1, dest_node=(a * 5 + s) % n_shards,
@@ -480,11 +482,13 @@ for backend, opts, pad in [
     host = Torus(nx=pad[0], ny=pad[1], nz=pad[2])
     hops = host.hops(ids[:, None], ids[None, :]).astype(np.int64)
     fmt = wire.get_profile("extoll")
+    # compiled like the device side, so the f32 mean sums in the same order
+    digest = jax.jit(lambda c, h, w: wire.summarize_latency(
+        wire.hop_latency_us(fmt, c, h), w))
     for me in range(n_shards):
         cnt = jnp.asarray(np.asarray(t.sent_counts)[me])
-        lat = wire.hop_latency_us(fmt, cnt, jnp.asarray(hops[me]))
         w8 = jnp.where(jnp.arange(n_shards) != me, cnt, 0)
-        exp = wire.summarize_latency(lat, w8)
+        exp = digest(cnt, jnp.asarray(hops[me]), w8)
         got = jax.tree_util.tree_map(lambda x: x[me], t.latency)
         for a, b in zip(exp, got):
             assert (np.asarray(a) == np.asarray(b)).all(), (backend, me)
@@ -504,15 +508,15 @@ def test_tenant_fabric_invariant_fuzz():
     out = run_md(r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.core import flow_control as fc
 from repro.transport.torus import TenantTorusTransport
 from repro.serve.loadgen import traffic_rng, draw_counts, draw_payload
 
 n, W, WINDOWS, SEEDS = 8, 6, 8, 4
-mesh = Mesh(np.array(jax.devices()[:n]), ("w",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(n, "w")
 
 CONFIGS = [
     # (reserves, link_credits, notify) — incl. a pure best-effort tenant
@@ -544,8 +548,8 @@ def run_case(part, notify, seed):
         lift = lambda t_: jax.tree.map(lambda a: a[None], t_)
         return lift(outs), lift((dr.state, dr.recv_counts, dr.stats))
 
-    f = jax.jit(shard_map(shard_fn, mesh=mesh, in_specs=(P("w"), P("w")),
-                          out_specs=(P("w"), P("w")), check_rep=False))
+    f = jax.jit(jax.shard_map(shard_fn, mesh=mesh, in_specs=(P("w"), P("w")),
+                              out_specs=(P("w"), P("w")), check_vma=False))
     cin = jnp.asarray(counts.transpose(2, 0, 1, 3))
     pin = jnp.asarray(payload.transpose(2, 0, 1, 3, 4))
     (rcnt, stats, states), (dstate, dcnt, dstats) = jax.tree.map(
@@ -610,7 +614,6 @@ def test_qos_isolation_engine_level():
     out = run_md(r"""
 import numpy as np
 import jax
-from jax.sharding import Mesh
 
 from repro.serve.loadgen import PoissonLoadGen, TenantProfile
 from repro.serve.spike_engine import EngineConfig, SpikeEngine
@@ -618,7 +621,8 @@ from repro.serve.tenancy import TenantSpec
 
 QOS_P99_BOUND = 4.0
 n = 8
-mesh = Mesh(np.array(jax.devices()[:n]), ("w",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(n, "w")
 tenants = [TenantSpec("quiet", reserve=32, rate_epw=40.0),
            TenantSpec("hot", reserve=8, rate_epw=400.0)]
 cfg = EngineConfig(capacity=16, link_credits=64, notify_latency=2,
@@ -672,7 +676,8 @@ from repro.snn import microcircuit as mc, network, simulator as sim
 spec = mc.MicrocircuitSpec(scale=0.003)
 w, is_inh = spec.weight_matrix()
 part = network.build_partition(w, is_inh, n_shards=8)
-mesh = jax.make_mesh((8,), ("wafer",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(8)
 dims = (2, 2, 2)
 N_WIN = 10
 for seed in (0, 1, 2):

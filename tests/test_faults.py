@@ -124,8 +124,7 @@ def test_single_link_down_smoke():
     out = run_md(r"""
 import functools
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 from repro import transport
 from repro.fabric import link_fault, mask_at
 from repro.serve.loadgen import traffic_rng, draw_counts
@@ -134,10 +133,11 @@ n, W, n_win, credits = 4, 4, 8, 8
 t = transport.create("torus2d", n_shards=n, nx=2, ny=2,
                      link_credits=credits, notify_latency=2)
 sched = link_fault((2, 2), n_win, 0, 0, start=1)
-mesh = Mesh(np.array(jax.devices()[:n]), ("w",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(n, "w")
 
-@functools.partial(shard_map, mesh=mesh, in_specs=(P("w"), P("w")),
-                   out_specs=P("w"), check_rep=False)
+@functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("w"), P("w")),
+                   out_specs=P("w"), check_vma=False)
 def body(counts, win_ids):
     state = t.init_state(payload_width=W)
     def step(state, x):
@@ -187,15 +187,15 @@ def test_liveness_dead_link_ample_credits():
     out = run_md(r"""
 import functools
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 from repro import transport
 from repro.fabric import cable_links, link_fault, mask_at
 from repro.serve.loadgen import traffic_rng, draw_counts
 
 D, W, n_win = 8, 4, 6
 AMPLE = 1 << 16
-mesh = Mesh(np.array(jax.devices()[:D]), ("w",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(D, "w")
 
 for name, dims, opts in [("torus2d", (2, 4), dict(nx=2, ny=4)),
                          ("torus3d", (2, 2, 2), dict(nx=2, ny=2, nz=2))]:
@@ -204,8 +204,8 @@ for name, dims, opts in [("torus2d", (2, 4), dict(nx=2, ny=4)),
     sched = link_fault(dims, n_win, 0, 0)
     dead = list(cable_links(dims, 0, 0))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(P("w"), P("w")),
-                       out_specs=P("w"), check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("w"), P("w")),
+                       out_specs=P("w"), check_vma=False)
     def body(counts, win_ids):
         state = t.init_state(payload_width=W)
         def step(state, x):

@@ -15,7 +15,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core import events as ev, routing as rt
 from repro.core.exchange import make_exchange
 n_shards, N, C, n_addr = 8, 32, 16, 64
-mesh = jax.make_mesh((n_shards,), ("wafer",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(n_shards)
 tabs = []
 for s in range(n_shards):
     projs = [rt.Projection(a, a+1, dest_node=a % n_shards, dest_links=[a % 3, 7])
@@ -55,7 +56,8 @@ part = network.build_partition(w, is_inh, n_shards=4)
 cfg = sim.SimConfig(n_shards=4, per_shard=part.per_shard,
                     max_fan=part.fanout.shape[1], window=8, ring_len=32,
                     e_max=256, capacity=512)
-mesh = jax.make_mesh((4,), ("wafer",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(4)
 init, run = sim.build_sharded_sim(mesh, "wafer", cfg, part, spec.bg_rates())
 st = init(0)
 st, stats = run(st, 8)
@@ -76,7 +78,8 @@ import jax, jax.numpy as jnp
 from repro.core import events as ev, routing as rt
 from repro.core.exchange import make_exchange
 n_shards, N, C, n_addr = 8, 32, 16, 64
-mesh = jax.make_mesh((n_shards,), ("wafer",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(n_shards)
 tabs = []
 for s in range(n_shards):
     projs = [rt.Projection(a, a+1, dest_node=a % n_shards, dest_links=[a % 3])
@@ -104,10 +107,10 @@ def test_moe_bucket_equals_local():
     out = run_md("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.configs.base import MoEConfig
 from repro.models import moe as M
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 moe = MoEConfig(n_experts=8, top_k=2, expert_ff=16, capacity_factor=8.0)
 d, T = 12, 32
 key = jax.random.PRNGKey(0)
@@ -126,10 +129,10 @@ def body(xl, router, wg, wu, wd):
                             "w_down": wd}, moe, axis="model", capacity=64)
     return y.reshape(xl.shape)
 
-fn = shard_map(body, mesh=mesh,
-               in_specs=(P("data", None), P(), P("model", None, None),
-                         P("model", None, None), P("model", None, None)),
-               out_specs=P("data", None), check_rep=False)
+fn = jax.shard_map(body, mesh=mesh,
+                   in_specs=(P("data", None), P(), P("model", None, None),
+                             P("model", None, None), P("model", None, None)),
+                   out_specs=P("data", None), check_vma=False)
 y2 = fn(x.reshape(2, T // 2, d).reshape(T, d),
         params["router"], params["w_gate"], params["w_up"], params["w_down"])
 np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y2), rtol=2e-4, atol=2e-4)
@@ -142,7 +145,8 @@ def test_compressed_allreduce():
     out = run_md("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.compression import make_compressed_allreduce, init_error_feedback
-mesh = jax.make_mesh((4,), ("pod",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("pod",))
 ar = make_compressed_allreduce(mesh, ("pod",))
 g = {"w": jnp.asarray(np.random.default_rng(0).normal(size=(64, 32)), jnp.float32)}
 e = init_error_feedback(g)
@@ -166,7 +170,8 @@ from repro.configs import get_config, SHAPES, reduced
 from repro.configs.base import ShapeConfig
 from repro.distributed import sharding as shd
 from repro.launch import dryrun as dr
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = get_config("qwen3_32b")
 import dataclasses
 cfg = dataclasses.replace(cfg, n_layers=2)          # keep compile small
@@ -188,20 +193,20 @@ def test_split_kv_decode_attention():
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.distributed.collectives import split_kv_decode_attention
-mesh = jax.make_mesh((4,), ("model",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("model",))
 B, T, Hq, Hkv, D = 2, 64, 8, 2, 16
 k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
 q = jax.random.normal(k1, (B, 1, Hq, D))
 k = jax.random.normal(k2, (B, T, Hkv, D))
 v = jax.random.normal(k3, (B, T, Hkv, D))
 clen = jnp.asarray(50)
-fn = shard_map(
+fn = jax.shard_map(
     partial(split_kv_decode_attention, axis_name="model"),
     mesh=mesh,
     in_specs=(P(), P(None, "model", None, None), P(None, "model", None, None), P()),
-    out_specs=P(), check_rep=False)
+    out_specs=P(), check_vma=False)
 o1 = fn(q, k, v, clen)
 # reference: full attention over valid prefix
 kk = jnp.repeat(k, Hq // Hkv, 2); vv = jnp.repeat(v, Hq // Hkv, 2)

@@ -301,11 +301,11 @@ def test_committed_trace_artifact_is_valid():
 # -- engine integration (1-shard, in-process) --------------------------------
 
 def _make_instrumented_engine(seed=3):
-    from jax.sharding import Mesh
     from repro.serve.loadgen import PoissonLoadGen, TenantProfile
     from repro.serve.spike_engine import EngineConfig, SpikeEngine
     from repro.serve.tenancy import TenantSpec
-    mesh = Mesh(np.array(jax.devices()[:1]), ("w",))
+    from repro.launch.mesh import make_wafer_mesh
+    mesh = make_wafer_mesh(1, "w")
     tenants = [TenantSpec("a", reserve=8, rate_epw=10.0),
                TenantSpec("b", reserve=4, rate_epw=30.0)]
     cfg = EngineConfig(capacity=8, link_credits=16, seg_windows=3,
@@ -351,11 +351,11 @@ def test_engine_determinism_unchanged_by_recorder():
     """The instrumented engine serves the EXACT same traffic outcome as an
     uninstrumented one on the same seed — the recorder observes, it never
     perturbs."""
-    from jax.sharding import Mesh
     from repro.serve.loadgen import PoissonLoadGen, TenantProfile
     from repro.serve.spike_engine import EngineConfig, SpikeEngine
     from repro.serve.tenancy import TenantSpec
-    mesh = Mesh(np.array(jax.devices()[:1]), ("w",))
+    from repro.launch.mesh import make_wafer_mesh
+    mesh = make_wafer_mesh(1, "w")
     tenants = [TenantSpec("a", reserve=8, rate_epw=10.0),
                TenantSpec("b", reserve=4, rate_epw=30.0)]
     cfg = EngineConfig(capacity=8, link_credits=16, seg_windows=3,
@@ -416,13 +416,13 @@ def test_sim_carry_structure_disabled():
 def test_disabled_path_hlo_pinned():
     out = run_md(r"""
 import numpy as np, jax
-from jax.sharding import Mesh
 from repro.obs import recorder as obs_recorder
 from repro.serve.loadgen import PoissonLoadGen, TenantProfile
 from repro.serve.spike_engine import EngineConfig, SpikeEngine
 from repro.serve.tenancy import TenantSpec
 
-mesh = Mesh(np.array(jax.devices()[:4]), ("w",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(4, "w")
 cfg = EngineConfig(capacity=8, link_credits=16, notify_latency=2,
                    window_us=100.0, seg_windows=3, nx=2, ny=2, nz=1)
 tenants = [TenantSpec("a", reserve=8, rate_epw=16.0),
@@ -464,7 +464,8 @@ from repro.snn import microcircuit as mc, network, simulator as sim
 spec = mc.MicrocircuitSpec(scale=0.003)
 w, is_inh = spec.weight_matrix()
 part = network.build_partition(w, is_inh, n_shards=8)
-mesh = jax.make_mesh((8,), ("wafer",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(8)
 N_WIN = 6
 for transport in ("alltoall", "torus2d", "torus3d"):
     kw = {}

@@ -16,7 +16,8 @@ def _build(capacity, residue, n_windows, scale=0.003, seed=0):
     cfg = sim.SimConfig(n_shards=1, per_shard=part.per_shard,
                         max_fan=part.fanout.shape[1], window=8, ring_len=32,
                         e_max=256, capacity=capacity, residue=residue)
-    mesh = jax.make_mesh((1,), ("wafer",))
+    from repro.launch.mesh import make_wafer_mesh
+    mesh = make_wafer_mesh(1)
     init, run = sim.build_sharded_sim(mesh, "wafer", cfg, part,
                                       spec.bg_rates())
     st = init(0)

@@ -10,16 +10,14 @@ the run with tracebacks, not hang CI.
 import numpy as np
 import pytest
 
-import jax
-from jax.sharding import Mesh
-
+from repro.launch.mesh import make_wafer_mesh
 from repro.serve.loadgen import PoissonLoadGen, TenantProfile, WindowTraffic
 from repro.serve.spike_engine import EngineConfig, SpikeEngine
 from repro.serve.tenancy import TenantLedger, TenantSpec
 
 
 def make_engine(seed=3, rate_b=30.0, segments_cfg=None, **cfg_kw):
-    mesh = Mesh(np.array(jax.devices()[:1]), ("w",))
+    mesh = make_wafer_mesh(1, "w")
     tenants = [TenantSpec("a", reserve=8, rate_epw=10.0),
                TenantSpec("b", reserve=4, rate_epw=rate_b)]
     kw = dict(capacity=8, link_credits=16, seg_windows=3, nx=1, ny=1, nz=1)
@@ -103,10 +101,25 @@ def test_engine_latency_attribution_counts_delivered():
             assert dig.p99_us >= dig.p50_us
 
 
+@pytest.mark.timeout(120)
+def test_engine_device_thread_failure_is_raised():
+    # a segment that fails on the device thread (as a compile error on the
+    # chip would) must surface from run() at once, not as a stop timeout
+    eng = make_engine()
+
+    def broken_segment(*args):
+        raise RuntimeError("injected segment failure")
+
+    eng._seg = broken_segment
+    with pytest.raises(RuntimeError, match="injected segment failure"):
+        eng.run(4, timeout=60.0)
+    assert eng._device_t is None and eng._ingest_t is None
+
+
 @pytest.mark.timeout(300)
 def test_engine_rejects_mismatched_source():
     src = PoissonLoadGen(0, [TenantProfile("a", 1.0)], 1, 8)
-    mesh = Mesh(np.array(jax.devices()[:1]), ("w",))
+    mesh = make_wafer_mesh(1, "w")
     cfg = EngineConfig(capacity=8, link_credits=16, nx=1, ny=1, nz=1)
     with pytest.raises(ValueError):
         SpikeEngine(mesh, "w", [TenantSpec("a", 8), TenantSpec("b", 4)],
@@ -142,7 +155,6 @@ def test_engine_link_death_mid_segment_conserves():
     out = run_md(r"""
 import numpy as np
 import jax
-from jax.sharding import Mesh
 
 from repro.fabric import link_fault
 from repro.serve.loadgen import PoissonLoadGen, TenantProfile
@@ -150,7 +162,8 @@ from repro.serve.spike_engine import EngineConfig, SpikeEngine
 from repro.serve.tenancy import TenantSpec
 
 n = 8
-mesh = Mesh(np.array(jax.devices()[:n]), ("w",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(n, "w")
 tenants = [TenantSpec("a", reserve=12, rate_epw=40.0),
            TenantSpec("b", reserve=10, rate_epw=20.0)]
 cfg = EngineConfig(capacity=16, link_credits=32, notify_latency=2,

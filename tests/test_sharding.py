@@ -77,7 +77,8 @@ def test_non_divisible_never_sharded():
 def test_bytes_per_device():
     import jax
     import jax.numpy as jnp
-    mesh = jax.make_mesh((1,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("model",))
     sds = {"a": jax.ShapeDtypeStruct((8, 8), jnp.float32)}
     from jax.sharding import NamedSharding
     sh = {"a": NamedSharding(mesh, P("model", None))}

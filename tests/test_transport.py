@@ -41,7 +41,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core import events as ev, routing as rt
 from repro.core.exchange import make_exchange
 n_shards, N, C, n_addr = 8, 64, 16, 96
-mesh = jax.make_mesh((n_shards,), ("wafer",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(n_shards)
 tabs = []
 for s in range(n_shards):
     projs = [rt.Projection(a, a+1, dest_node=(a * 5 + s) % n_shards,
@@ -110,12 +111,12 @@ def test_torus_hop_by_hop_credit_conservation_property():
     out = run_md("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro import transport
 from repro.core import flow_control as fc
 
 D, W = 8, 6
-mesh = jax.make_mesh((D,), ("wafer",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(D)
 spec = P("wafer")
 
 def make_fns(t):
@@ -132,11 +133,11 @@ def make_fns(t):
         return jax.tree_util.tree_map(
             lambda x: x[None], (out.state, out.recv_counts, out.stats))
     import functools
-    mk = lambda enforce: jax.jit(shard_map(
+    mk = lambda enforce: jax.jit(jax.shard_map(
         functools.partial(body, enforce=enforce), mesh=mesh,
-        in_specs=(spec, spec, spec), out_specs=spec, check_rep=False))
-    walk = jax.jit(shard_map(dbody, mesh=mesh, in_specs=(spec,),
-                             out_specs=spec, check_rep=False))
+        in_specs=(spec, spec, spec), out_specs=spec, check_vma=False))
+    walk = jax.jit(jax.shard_map(dbody, mesh=mesh, in_specs=(spec,),
+                                 out_specs=spec, check_vma=False))
     return mk(True), mk(False), walk
 
 rng = np.random.default_rng(0)
@@ -361,7 +362,8 @@ from repro.snn import microcircuit as mc, network, simulator as sim
 spec = mc.MicrocircuitSpec(scale=0.003)
 w, is_inh = spec.weight_matrix()
 part = network.build_partition(w, is_inh, n_shards=4)
-mesh = jax.make_mesh((4,), ("wafer",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(4)
 
 def run(transport, link_credits=0, capacity=512, n_windows=8, **kw):
     cfg = sim.SimConfig(n_shards=4, per_shard=part.per_shard,

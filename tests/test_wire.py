@@ -265,7 +265,8 @@ def _run_sim(wire_format, n_windows=10):
     cfg = sim.SimConfig(n_shards=1, per_shard=part.per_shard,
                         max_fan=part.fanout.shape[1], window=8, ring_len=32,
                         e_max=256, capacity=512, wire_format=wire_format)
-    mesh = jax.make_mesh((1,), ("wafer",))
+    from repro.launch.mesh import make_wafer_mesh
+    mesh = make_wafer_mesh(1)
     init, run = sim.build_sharded_sim(mesh, "wafer", cfg, part,
                                       spec.bg_rates())
     _, stats = run(init(0), n_windows)
@@ -310,7 +311,8 @@ from repro.core import events as ev, routing as rt
 from repro.core.exchange import make_exchange
 from repro.core.torus import Torus
 n_shards, N, C, n_addr = 8, 256, 64, 256
-mesh = jax.make_mesh((n_shards,), ("wafer",))
+from repro.launch.mesh import make_wafer_mesh
+mesh = make_wafer_mesh(n_shards)
 tabs = []
 for s in range(n_shards):
     projs = [rt.Projection(a, a+1, dest_node=(a * 5 + s) % n_shards,

@@ -47,7 +47,6 @@ def main() -> None:
 
     import numpy as np
     import jax
-    from jax.sharding import Mesh
 
     from repro.obs import metrics as obs_metrics
     from repro.obs import recorder as obs_recorder
@@ -57,7 +56,8 @@ def main() -> None:
     from repro.serve.spike_engine import EngineConfig, SpikeEngine
     from repro.serve.tenancy import TenantSpec
 
-    mesh = Mesh(np.array(jax.devices()[:4]), ("w",))
+    from repro.launch.mesh import make_wafer_mesh
+    mesh = make_wafer_mesh(4, "w")
     cfg = EngineConfig(capacity=8, link_credits=16, notify_latency=2,
                       window_us=100.0, seg_windows=3, nx=2, ny=2, nz=1)
     tenants = [TenantSpec("a", reserve=8, rate_epw=16.0),
