@@ -6,6 +6,11 @@ format — open the written file directly in https://ui.perfetto.dev or
 ``chrome://tracing``.  Timestamps are microseconds since the tracer's
 creation (``time.perf_counter_ns`` based, so monotonic per process).
 
+An enabled tracer's ``span()`` also enters ``jax.profiler.TraceAnnotation``
+of the same name, so while a ``jax.profiler`` session is active the span
+lands on the profiler's host plane, on the same clock as the device
+trace: a device idle gap can be named by the host span that covers it.
+
 Design constraints (serving-engine hot path):
 
 * **Cheap when disabled** — ``Tracer(enabled=False)`` (or the shared
@@ -29,6 +34,8 @@ import json
 import threading
 import time
 from contextlib import contextmanager
+
+import jax
 
 
 class SpanHandle:
@@ -83,10 +90,16 @@ class Tracer:
         The yielded :class:`SpanHandle` keeps timing even when the tracer
         is disabled, so ``sp.dur_s`` can feed existing wall-clock
         consumers (straggler checks, throughput math) unconditionally.
+        Enabled, the block also runs inside a ``jax.profiler``
+        annotation of the same name.
         """
         sp = SpanHandle(name, self.now_us(), dict(args))
         try:
-            yield sp
+            if self.enabled:
+                with jax.profiler.TraceAnnotation(name):
+                    yield sp
+            else:
+                yield sp
         finally:
             sp.dur_us = self.now_us() - sp.t0_us
             if self.enabled:

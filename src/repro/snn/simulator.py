@@ -45,7 +45,9 @@ dropped).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 from typing import NamedTuple
 
 import jax
@@ -58,6 +60,7 @@ from repro import wire
 from repro.core import aggregator, events as ev
 from repro.fabric import faults as fabric_faults
 from repro.obs import recorder as obs_recorder
+from repro.obs import spans as obs_spans
 from repro.core.routing import RoutingTables
 from repro.snn import lif, network
 
@@ -485,6 +488,29 @@ def make_pipeline_fns(cfg: SimConfig, *, axis_name: str | None,
     return init_pending, init_link, body, drain, init_ring
 
 
+# what ``jax.monitoring`` calls one XLA compilation of a program (also a
+# persistent-cache hit), in ``jax._src.dispatch``
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@contextlib.contextmanager
+def counting_compiles():
+    """Count the XLA compilations the calling thread makes inside the
+    block; yields a one-element list that holds the count.  The
+    ``jax.monitoring`` listener is registered only for the block."""
+    n, me = [0], threading.get_ident()
+
+    def listen(event: str, duration_s: float, **_):
+        if event == BACKEND_COMPILE_EVENT and threading.get_ident() == me:
+            n[0] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        yield n
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+
+
 class SimCarry(NamedTuple):
     """Resumable between-segment state of a sharded simulation: everything
     the window pipeline threads through ``lax.scan`` — neuron/ring state,
@@ -509,7 +535,7 @@ def build_sharded_segments(mesh, axis_name: str, cfg: SimConfig,
                            bg_weight: float = 87.8,
                            fault_schedule: fabric_faults.FaultSchedule |
                            None = None,
-                           recorder=None):
+                           recorder=None, tracer=None):
     """Segment-granular jitted simulator over a device mesh.
 
     The whole-run scan of :func:`build_sharded_sim` is a special case of
@@ -532,7 +558,23 @@ def build_sharded_segments(mesh, axis_name: str, cfg: SimConfig,
                                        ``drain_fabric`` + one uncredited
                                        exchange; no event is lost between
                                        segment end and shutdown
+      run_segment.fetch_stats(stats) -> the segment's WindowStats on the
+                                       host
+
+    ``tracer`` (a ``repro.obs.spans.Tracer``; ``None`` is the disabled
+    ``NULL``) records the host's segment cycle, one ``seg`` number per
+    segment: ``segment/dispatch`` around the jitted call (``n_windows``;
+    ``compiles``, the XLA compilations inside the call), then, in
+    ``fetch_stats``, ``segment/wait`` until the device has finished and
+    ``segment/fetch`` while the statistics are copied.  The first fetch
+    of each segment length also counts what it copies (``arrays``,
+    device buffers; ``bytes``).  An enabled tracer keeps each segment's
+    statistics with its number until ``fetch_stats`` takes them, so a
+    caller may fetch one segment while the next is dispatched.  The
+    spans are host-only: the lowered segment and the carry are the same
+    with or without them.
     """
+    tracer = obs_spans.NULL if tracer is None else tracer
     S, per = cfg.n_shards, cfg.per_shard
     n_tot = part.n_neurons
     spec = P(axis_name)
@@ -596,10 +638,44 @@ def build_sharded_segments(mesh, axis_name: str, cfg: SimConfig,
         return jax.jit(fn)
 
     operands = (dest_t, guid_t, mcast_t, w_exc, w_inh, delays, bg)
+    # the segment cycle on the host: segments are numbered as dispatched;
+    # with the tracer on, id(stats) -> (stats, seg, n_windows) until
+    # fetch_stats takes them, and the segment lengths already counted
+    cycle = {"seg": -1, "issued": {}, "sized": set()}
 
     def run_segment(carry: SimCarry, n_windows: int):
-        return _compiled_segment(n_windows)(carry, *operands)
+        cycle["seg"] += 1
+        seg = cycle["seg"]
+        with tracer.span("segment/dispatch", seg=seg,
+                         n_windows=n_windows) as sp:
+            with (counting_compiles() if tracer.enabled
+                  else contextlib.nullcontext([0])) as n:
+                out, stats = _compiled_segment(n_windows)(carry, *operands)
+            sp.args["compiles"] = n[0]
+        if tracer.enabled:
+            cycle["issued"][id(stats)] = (stats, seg, n_windows)
+        return out, stats
 
+    def fetch_stats(stats: WindowStats) -> WindowStats:
+        _, seg, n_windows = cycle["issued"].pop(id(stats), (None,) * 3)
+        with tracer.span("segment/wait", seg=seg):
+            # start every copy first, as jax.device_get does: each runs
+            # as soon as the device has finished
+            for x in jax.tree_util.tree_leaves(stats):
+                x.copy_to_host_async()
+            jax.block_until_ready(stats)
+        with tracer.span("segment/fetch", seg=seg) as sp:
+            host = jax.device_get(stats)
+            # (only statistics an enabled tracer saw dispatched are known)
+            if n_windows is not None and n_windows not in cycle["sized"]:
+                cycle["sized"].add(n_windows)
+                leaves = jax.tree_util.tree_leaves(host)
+                # every leaf is split over the S shards (out_specs)
+                sp.args.update(arrays=len(leaves) * S,
+                               bytes=sum(x.nbytes for x in leaves))
+        return host
+
+    run_segment.fetch_stats = fetch_stats
     # the lowered segment, for checking what the compiler made of it
     run_segment.lower = lambda carry, n_windows: _compiled_segment(
         n_windows).lower(carry, *operands)

@@ -71,6 +71,44 @@ def test_tracer_disabled_still_times():
     assert sp.dur_us >= 0.0
 
 
+def _profiled_host_events(trace_dir, body):
+    """Host events of a ``jax.profiler`` trace of ``body()``."""
+    import glob
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    return [(e.name, e.start_ns, e.duration_ns)
+            for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:CPU") for line in p.lines
+            for e in line.events]
+
+
+def test_enabled_span_lands_in_the_profiler_trace(tmp_path):
+    tr = obs_spans.Tracer()
+    spans = {}
+
+    def body():
+        with tr.span("segment/fetch", seg=3) as sp:
+            np.asarray(jnp.ones(8).sum())
+        spans["fetch"] = sp
+        with obs_spans.NULL.span("segment/hidden"):
+            pass
+
+    host = _profiled_host_events(tmp_path, body)
+    got = [h for h in host if h[0].startswith("segment/")]
+    assert [h[0] for h in got] == ["segment/fetch"]
+    # the profiler's span lies inside the tracer's own timing of it
+    assert 0 < got[0][2] <= spans["fetch"].dur_us * 1e3
+    # the in-memory record is unchanged
+    ev, = [e for e in tr.to_dict()["traceEvents"] if e["ph"] == "X"]
+    assert ev["name"] == "segment/fetch" and ev["args"] == {"seg": 3}
+
+
 def test_validate_trace_detects_problems():
     bad = {"traceEvents": [
         {"name": "a", "ph": "X", "ts": 5.0, "dur": -1.0, "pid": 0, "tid": 0},
