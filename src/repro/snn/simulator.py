@@ -204,12 +204,17 @@ def _spikes_to_events(spikes: jax.Array, t0: jax.Array, delays: jax.Array,
 
 
 def _apply_events(state: ShardState, words: jax.Array, counts: jax.Array,
-                  w_cols_exc: jax.Array, w_cols_inh: jax.Array,
+                  w_rows_exc: jax.Array, w_rows_inh: jax.Array,
                   cfg: SimConfig, src_shard: jax.Array):
     """Scatter weighted input of received events into the delay ring.
 
     words: (n_shards, C) events from each source shard; counts (n_shards,).
-    w_cols_*: (per, n_total) local weight rows split by source sign.
+    w_rows_*: (n_total, >= per) source-major local weights split by
+    source sign: row j holds source j's synapses onto this shard's
+    neurons, in its first ``per`` columns (see :func:`_source_major`).
+    An event gathers its source's row; gathering a column of a
+    target-major matrix instead makes XLA re-lay out the whole matrix
+    on every call.
     Returns (state, deadline_misses).
     """
     S, C = words.shape
@@ -227,13 +232,52 @@ def _apply_events(state: ShardState, words: jax.Array, counts: jax.Array,
     flat_live = live.reshape(-1)
     flat_src = jnp.where(flat_live, src_global.reshape(-1), 0)
     flat_slot = slot.reshape(-1)
-    # one-hot over ring slots x gathered weight columns
-    exc_cols = w_cols_exc[:, flat_src] * flat_live[None, :]       # (per, S*C)
-    inh_cols = w_cols_inh[:, flat_src] * flat_live[None, :]
-    onehot = jax.nn.one_hot(flat_slot, cfg.ring_len, dtype=exc_cols.dtype)
-    ring_exc = state.ring_exc + jnp.einsum("el,pe->lp", onehot, exc_cols)
-    ring_inh = state.ring_inh + jnp.einsum("el,pe->lp", onehot, inh_cols)
+    # one-hot over ring slots x gathered weight rows; whole rows, as the
+    # TPU gathers them natively (a gather of part of a row is expanded
+    # into a loop of one-row slices), cut to ``per`` after the product
+    exc_rows = w_rows_exc[flat_src] * flat_live[:, None]          # (S*C, W)
+    inh_rows = w_rows_inh[flat_src] * flat_live[:, None]
+    onehot = jax.nn.one_hot(flat_slot, cfg.ring_len, dtype=exc_rows.dtype)
+    per = cfg.per_shard
+    ring_exc = state.ring_exc + jnp.einsum("el,ep->lp", onehot,
+                                           exc_rows)[:, :per]
+    ring_inh = state.ring_inh + jnp.einsum("el,ep->lp", onehot,
+                                           inh_rows)[:, :per]
     return state._replace(ring_exc=ring_exc, ring_inh=ring_inh), miss
+
+
+# the TPU's vector lanes: an array's minor dimension is laid out in
+# whole multiples of this
+LANES = 128
+
+
+def weight_width(per: int) -> int:
+    """Columns of the apply's source-major weights: ``per`` rounded up to
+    whole lanes."""
+    return -(-per // LANES) * LANES
+
+
+def _source_major(w_local: np.ndarray, keep: np.ndarray,
+                  block: int = 512) -> np.ndarray:
+    """(S, per, N) target-major weights -> (S, N, weight_width(per))
+    source-major float32, the rows of sources outside ``keep`` and the
+    columns past ``per`` zero.  One pass, in blocks of target columns so
+    both sides of the transpose stay in cache.
+
+    The width in whole lanes is what makes the TPU's default layout of a
+    shard's 2-D block row-major, the layout the apply's row gather
+    reads: by default the TPU puts minor whichever dimension pads less
+    (a (16204, 4051) block would be column-major), and lays a leading
+    axis of one out in (1, 128) tiles.  XLA would copy the whole matrix
+    into the gather's layout on every call of the segment program."""
+    S, per, n = w_local.shape
+    out = np.zeros((S, n, weight_width(per)), np.float32)
+    for s in range(S):
+        rows = out[s, :, :per]
+        for j in range(0, per, block):
+            np.copyto(rows[:, j:j + block], w_local[s, j:j + block].T,
+                      where=keep[:, None])
+    return out
 
 
 def make_pipeline_fns(cfg: SimConfig, *, axis_name: str | None,
@@ -545,6 +589,15 @@ def build_sharded_segments(mesh, axis_name: str, cfg: SimConfig,
     device work and decide, between segments, whether to keep serving or
     quiesce).
 
+    The per-shard operands are placed on the mesh once, shard s on
+    device s.  The routing tables (``dest_of_addr``, ``guid_of_addr``,
+    ``mcast_of_guid``, padded to a common length), the axonal delays
+    ``(S, per)`` and the background rates ``(S, per)`` are stacked over
+    a leading shard axis.  The excitatory and inhibitory weights are
+    source-major, each shard's ``(n_total, weight_width(per))`` block
+    stacked along the rows into ``(S * n_total, weight_width(per))``:
+    the apply gathers a row per event (see :func:`_source_major`).
+
     Returns ``(init, run_segment, finish)``:
       init(seed)                    -> SimCarry (fresh neurons, empty
                                        buckets, full credits)
@@ -583,8 +636,10 @@ def build_sharded_segments(mesh, axis_name: str, cfg: SimConfig,
     put = functools.partial(jax.device_put, device=shard)
     w_local, _fan, delay_local = network.shard_arrays(part)
     is_inh = part.is_inh
-    w_exc = put(np.where(~is_inh[None, :], w_local, 0.0).astype(np.float32))
-    w_inh = put(np.where(is_inh[None, :], w_local, 0.0).astype(np.float32))
+    # source-major, each shard's block stacked along the rows: see
+    # _source_major
+    w_exc = put(_source_major(w_local, ~is_inh).reshape(S * n_tot, -1))
+    w_inh = put(_source_major(w_local, is_inh).reshape(S * n_tot, -1))
     delays = put(delay_local)
     tabs = [network.routing_tables_for_shard(part, s) for s in range(S)]
     # pad per-shard tables to a common size before stacking
@@ -608,7 +663,7 @@ def build_sharded_segments(mesh, axis_name: str, cfg: SimConfig,
         c0 = jax.tree_util.tree_map(lambda x: x[0], carry)
 
         def win(c, _):
-            return body(c, tables, w_e[0], w_i[0], dl[0], bgr[0],
+            return body(c, tables, w_e, w_i, dl[0], bgr[0],
                         bg_weight)
 
         if recorder is not None:
@@ -625,7 +680,7 @@ def build_sharded_segments(mesh, axis_name: str, cfg: SimConfig,
 
     def fin_fn(carry: SimCarry, w_e, w_i):
         c0 = jax.tree_util.tree_map(lambda x: x[0], carry)
-        st, miss_d = drain(c0.state, c0.pending, c0.link, w_e[0], w_i[0])
+        st, miss_d = drain(c0.state, c0.pending, c0.link, w_e, w_i)
         return (jax.tree_util.tree_map(lambda x: x[None], st),
                 miss_d[None])
 

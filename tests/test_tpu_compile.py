@@ -9,6 +9,8 @@ and what interpret mode on the CPU cannot see.
 The topology is described inside a fixture, never at import: one process
 at a time may load the TPU library.
 """
+import re
+
 import pytest
 
 import jax
@@ -83,18 +85,34 @@ def test_codec_kernels_compile(one_chip, n_dest, capacity):
     assert "tpu_custom_call" in enc and "tpu_custom_call" in dec
 
 
-@pytest.mark.parametrize("n_shards,per,fan,transport", [
+# the smoke sizes: the microcircuit at scale 0.21 on one ``wafer`` device,
+# and on four; the four-node torus is uncredited, so its stall histograms
+# fold to constants: the TPU compiler's scatter emitter aborts on such
+# scatter-adds
+WINDOW_BODIES = pytest.mark.parametrize("n_shards,per,fan,transport", [
     (1, 16202, 1, dict(transport="alltoall")),
-    # uncredited, so the torus's stall histograms fold to constants: the
-    # TPU compiler's scatter emitter aborts on such scatter-adds
     (4, 4051, 4, dict(transport="torus3d", torus_nx=1, torus_ny=2,
                       torus_nz=2)),
 ])
-def test_window_body_compiles(topo, monkeypatch, n_shards, per, fan,
-                              transport):
-    """The simulator's window body (exchange + decode, LIF steps, spike
-    compaction, route + placement) at the smoke sizes: the microcircuit
-    at scale 0.21 on one ``wafer`` device, and on four."""
+SEGMENT_WINDOWS = 8
+
+
+@pytest.fixture(scope="module")
+def segments():
+    """Compiled segment texts of this module, by size."""
+    return {}
+
+
+def _segment_hlo(segments, topo, monkeypatch, n_shards, per, fan,
+                 transport) -> str:
+    """The compiled text of the simulator's window body (exchange +
+    decode, LIF steps, spike compaction, route + placement) scanned over
+    a segment's windows, as ``build_sharded_segments`` runs it; the
+    weights source-major, ``(n, weight_width(per))`` a shard, stacked
+    along the rows as the program places them.  Compiled once a size."""
+    key = (n_shards, per)
+    if key in segments:
+        return segments[key]
     # the body picks its kernels from the backend it sees; here that is
     # the CPU, so point it at the chip it is compiled for
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
@@ -124,16 +142,56 @@ def test_window_body_compiles(topo, monkeypatch, n_shards, per, fan,
     tables = RoutingTables(stacked((per * fan,), jnp.int32),
                            stacked((per * fan,), jnp.int32),
                            stacked((per * fan,), jnp.uint32))
-    w = stacked((per, n), jnp.float32)
+    w = jax.ShapeDtypeStruct((n_shards * n, sim.weight_width(per)),
+                             jnp.float32, sharding=shard)
 
-    def window(c, t, we, wi, dl, bg):
+    def segment(c, t, we, wi, dl, bg):
         mine = lambda tree: jax.tree.map(lambda a: a[0], tree)
-        out = body(mine(c), mine(t), we[0], wi[0], dl[0], bg[0], 87.8)
+        tabs = mine(t)
+        win = lambda c, _: body(c, tabs, we, wi, dl[0], bg[0], 87.8)
+        out = jax.lax.scan(win, mine(c), None, length=SEGMENT_WINDOWS)
         return jax.tree.map(lambda a: a[None], out)
 
-    fn = jax.shard_map(window, mesh=mesh, in_specs=P("wafer"),
+    fn = jax.shard_map(segment, mesh=mesh, in_specs=P("wafer"),
                        out_specs=P("wafer"), check_vma=False)
-    txt = _hlo(fn, carry, tables, w, w, stacked((per,), jnp.int32),
-               stacked((per,), jnp.float32))
+    segments[key] = _hlo(fn, carry, tables, w, w,
+                         stacked((per,), jnp.int32),
+                         stacked((per,), jnp.float32))
+    return segments[key]
+
+
+@WINDOW_BODIES
+def test_window_body_compiles(segments, topo, monkeypatch, n_shards, per,
+                              fan, transport):
+    """The simulator's window body compiles for the chip at the smoke
+    sizes, its Pallas kernels kept."""
+    txt = _segment_hlo(segments, topo, monkeypatch, n_shards, per, fan,
+                       transport)
     assert txt.count("tpu_custom_call") >= 3     # placement, encode, decode
     assert ev.ADDR_MASK + 1 >= per * fan         # no address aliasing
+
+
+@WINDOW_BODIES
+def test_segment_keeps_weight_layout(segments, topo, monkeypatch, n_shards,
+                                     per, fan, transport):
+    """No copy, transpose or convert in the compiled segment makes an
+    array of a weight operand's size, in any order, layout or dtype: the
+    apply gathers rows of the source-major weights as they are stored,
+    instead of re-laying out the whole matrix once per segment call.
+    And the two gathers (excitatory, inhibitory) stay gathers of whole
+    rows, of the S * C = 4096 event slots of a window, not loops of
+    one-row slices."""
+    txt = _segment_hlo(segments, topo, monkeypatch, n_shards, per, fan,
+                       transport)
+    n = per * n_shards
+    weight = [sorted((n, per)), sorted((n, sim.weight_width(per)))]
+    relayouts = [
+        line.strip() for line in txt.splitlines()
+        if (m := re.search(r"= \w+\[([\d,]+)\]\S* (copy|transpose|convert)\(",
+                           line))
+        and sorted(d for d in map(int, m.group(1).split(",")) if d > 1)
+        in weight]
+    assert re.search(r"= f32\[[\d,]+\]\S* (copy|transpose)\(", txt)
+    assert relayouts == []
+    rows = rf"= f32\[4096,{sim.weight_width(per)}\]\S* gather\("
+    assert len(re.findall(rows, txt)) == 2
