@@ -5,11 +5,30 @@ Layout, under the benchmark's directory::
 
     workloads/<cell>.json   config, traffic, chips, why, limits
     configs/<config>.json   one deployment
-    traffic/<traffic>.json  one traffic mix
+    traffic/<traffic>.json  one traffic mix; ``"drive"`` names its drive
+    drives/<drive>.py       one drive (``background`` if the mix names none)
     metrics/<metric>.json   one per-layer metric (+ <metric>.py)
 
 A cell added later is a new file under each of these; nothing here
 names one.
+
+A drive is the stimulus a mix offers the network, given to both sides
+of the check by two functions:
+
+    program_inputs(cell, part) -> dict
+        the keyword arguments of ``simulator.build_sharded_segments``
+        after ``(mesh, axis_name, sim_config, part)``, such as
+        ``bg_rates`` and ``bg_weight``;
+    reference_window(cell, rnet) -> window_fn
+        the reference's window, ``(state, w, arrivals) -> (state,
+        spikes)`` as ``reference.segment.make_window`` builds it.
+
+The reference restarts from the program's state at the start of any
+sampled segment, so a drive's stimulus may depend only on that plain
+state (``t``, ``key``, neurons, rings) and on data the drive makes from
+the mix and a fixed seed of its own.  A stimulus periodic in the step
+counter, or a raster made once and handed to both sides, meets this; a
+PRNG stream carried only in the program's state does not.
 """
 from __future__ import annotations
 

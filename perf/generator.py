@@ -1,18 +1,15 @@
 """The one traffic generator: a mix's parameters -> the drive and the
 loop a run offers the program.
 
-A mix (``traffic/<name>.json``) states the background drive of every
-population (in-degree x rate, Poisson, one stream per node), how many
-windows one dispatched segment holds, and how many segments the checks
-sample.  Every seed gets the same drive, the same segment length and the
+A mix (``traffic/<name>.json``) names its drive (``"drive"``, a module
+under ``drives/``; ``background`` where it names none) with that drive's
+parameters, and states how many windows one dispatched segment holds,
+how many segments warm up, and how many the checks sample and the trace
+covers.  Every seed gets the same drive, the same segment length and the
 same number of samples; the seed changes only the initial membrane
 potentials, the Poisson streams and which segments are sampled.
 """
 from __future__ import annotations
-
-import numpy as np
-
-from perf.reference import pd2014
 
 # the program's init takes seed * 1000 + node into a 32-bit key
 PROGRAM_SEEDS = 2_000_000
@@ -20,10 +17,3 @@ PROGRAM_SEEDS = 2_000_000
 
 def program_seed(seed: int) -> int:
     return int(seed) % PROGRAM_SEEDS
-
-
-def background(cfg: dict, traffic: dict) -> np.ndarray:
-    """Per-neuron Poisson background rate [Hz]."""
-    return pd2014.background_rates(cfg["network"]["scale"],
-                                   traffic["bg_rate_hz"],
-                                   traffic["bg_indegree"])

@@ -5,10 +5,11 @@
 A cell is a deployment (``configs/``) under a traffic mix (``traffic/``),
 named by ``workloads/<cell>.json``.  The run builds the deployment's
 network (cached under ``.cache/`` per checkout), builds the simulator
-with ``simulator.build_sharded_segments`` on the cell's chips, starts
-from ``init(seed)``, warms the segment shape up, and then runs a closed
-loop for ``--seconds``: dispatch one segment, wait until its window
-statistics are on the host, repeat.  After the window it reads peak
+with ``simulator.build_sharded_segments`` on the cell's chips, handed
+the inputs of the drive the mix names (``drives/``, see ``deploy``),
+starts from ``init(seed)``, warms the segment shape up, and then runs a
+closed loop for ``--seconds``: dispatch one segment, wait until its
+window statistics are on the host, repeat.  After the window it reads peak
 device memory, frees the program and compares sampled segments with the
 plain reference (``reference/``), which decides ``correct``.
 
@@ -70,17 +71,27 @@ def _devices(jax, chips: int, require_tpu: bool):
     return devs[:chips]
 
 
-def build(cell: dict, devs):
-    """The timed path as a user builds it: (init, run_segment, finish)."""
+def drive(cell: dict):
+    """The module of the drive the cell's mix names (``drives/``); a mix
+    that names none is driven by ``background``."""
+    return _load_reader(cell["root"], "drives",
+                        cell["traffic"].get("drive", "background"))
+
+
+def build(cell: dict, devs, tracer=None):
+    """The timed path as a user builds it: the partition and (init,
+    run_segment, finish), the simulator handed the drive's inputs and
+    ``tracer``."""
     from repro.launch.mesh import make_wafer_mesh
     from repro.snn import simulator as sim
+    drv = drive(cell)
     cfg = cell["config"]
     part = deploy.partition(cfg, cell["cache"])
     sc = deploy.sim_config(cfg, part)
     mesh = make_wafer_mesh(len(devs), devices=devs)
-    bg = generator.background(cfg, cell["traffic"])
     return part, sim.build_sharded_segments(
-        mesh, "wafer", sc, part, bg, cfg["network"]["bg_weight_pa"])
+        mesh, "wafer", sc, part, tracer=tracer,
+        **drv.program_inputs(cell, part))
 
 
 def timed_loop(jax, run_segment, carry, seconds: float, n_win: int,
@@ -133,8 +144,10 @@ def _sum(stats, get) -> int:
 
 
 def open_cell(workload: str, root: str, require_tpu: bool):
-    """The cell's files and the chips it runs on."""
+    """The cell's files and the chips it runs on.  A drive that is not
+    there fails here, before any device is touched."""
     cell = deploy.load_cell(workload, root)
+    drive(cell)
     import jax
     devs = _devices(jax, cell["workload"]["chips"], require_tpu)
     if require_tpu:
@@ -246,16 +259,13 @@ def reference_setup(cell: dict, precisions):
     """The reference's network, window function and device weights
     rounded to each of ``precisions``."""
     import jax.numpy as jnp
-    cfg, mix = cell["config"], cell["traffic"]
+    cfg = cell["config"]
     fab, net = cfg["fabric"], cfg["network"]
     w, inh = deploy.reference_weights(cfg, cell["cache"])
     rnet = segment.Network(w, inh, fab["n_shards"], net["delay_exc_steps"],
                            net["delay_inh_steps"])
     del w
-    bg = np.pad(generator.background(cfg, mix),
-                (0, rnet.n - cfg["network"]["neurons"]))
-    window_fn = segment.make_window(cfg["lif"], bg, net["bg_weight_pa"],
-                                    fab["n_shards"], fab["window"])
+    window_fn = drive(cell).reference_window(cell, rnet)
     w_all = jnp.asarray(rnet.w)
     weights = {p: segment.round_to(w_all, p) for p in precisions}
     del w_all
@@ -283,9 +293,13 @@ def reference_readings(cell: dict, ref, samples, precision: str,
     return prog, ctl
 
 
-def _load_reader(root: str, name: str):
-    path = os.path.join(root, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"perf_metric_{name}",
+def _load_reader(root: str, kind: str, name: str):
+    """The module ``<root>/<kind>/<name>.py``: a per-layer metric's
+    reader (``metrics``) or a drive (``drives``)."""
+    path = os.path.join(root, kind, f"{name}.py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no {kind} module {name!r}: {path}")
+    spec = importlib.util.spec_from_file_location(f"perf_{kind}_{name}",
                                                   path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
@@ -328,7 +342,7 @@ def layer_readings(cell, layer_metrics, trace_dir, module, stats,
                    else red["layer_s"].get(d["layer"], 0.0))
         per_window_s = layer_s / n_windows
         if d["reduce"] == "roofline_share":
-            w = _load_reader(root, m["name"]).work(ctx)
+            w = _load_reader(root, "metrics", m["name"]).work(ctx)
             least = max(w["flops"] / peaks["bf16_flops_per_s"],
                         w["bytes"] / peaks["hbm_bytes_per_s"])
             if per_window_s <= 0 or least <= 0:

@@ -49,7 +49,7 @@ sys.path.insert(0, os.path.dirname(HERE))
 
 import numpy as np  # noqa: E402
 
-from perf import deploy, generator, harness, trace  # noqa: E402
+from perf import generator, harness, trace  # noqa: E402
 
 HOST_SPANS = ("bench.", "segment/")
 UNNAMED = "host: none annotated"
@@ -170,15 +170,7 @@ def slowest(splits: dict, k: int = 5) -> list:
 
 def build(cell: dict, devs, tracer):
     """``harness.build``'s program, with the simulator handed ``tracer``."""
-    from repro.launch.mesh import make_wafer_mesh
-    from repro.snn import simulator as sim
-    cfg = cell["config"]
-    part = deploy.partition(cfg, cell["cache"])
-    return sim.build_sharded_segments(
-        make_wafer_mesh(len(devs), devices=devs), "wafer",
-        deploy.sim_config(cfg, part), part,
-        generator.background(cfg, cell["traffic"]),
-        cfg["network"]["bg_weight_pa"], tracer=tracer)
+    return harness.build(cell, devs, tracer=tracer)[1]
 
 
 def cycle_loop(run_segment, carry, seconds: float, n_win: int,

@@ -27,7 +27,7 @@ def _ctx(capacity=4096, e_max=4096):
 
 
 def _work(name, ctx):
-    return harness._load_reader(PERF, name).work(ctx)
+    return harness._load_reader(PERF, "metrics", name).work(ctx)
 
 
 def test_hand_counts():

@@ -17,7 +17,8 @@ def make(tmp, nodes: int = 1) -> str:
     root = os.path.join(str(tmp), "perf")
     if not os.path.exists(root):
         os.makedirs(root)
-        for d in ("metrics", "traffic", "workloads", "configs"):
+        for d in ("metrics", "traffic", "workloads", "configs",
+                  "drives"):
             shutil.copytree(os.path.join(PERF, d), os.path.join(root, d))
         shutil.copy(os.path.join(PERF, "peaks.json"), root)
         shutil.copy(os.path.join(REPO, "BENCHMARK.json"), str(tmp))
